@@ -161,16 +161,22 @@ def _run_local(quick: bool) -> dict:
 
 
 def run(quick: bool = False) -> dict:
-    """Entry point for benchmarks.run: re-exec in a subprocess with 8
-    faked devices when this process's jax sees fewer than 4 (XLA_FLAGS
-    is consumed at first jax import, too late to set here)."""
+    """Entry point for benchmarks.run: a CPU parent that sees fewer
+    than 4 devices re-execs in a subprocess with 8 faked CPU devices
+    (XLA_FLAGS is consumed at first jax import, too late to set here).
+    An accelerator parent with fewer than 4 devices raises: it holds
+    the chip, and a CPU child would quietly benchmark the host."""
     import jax
     if jax.device_count() >= 4:
         return _run_local(quick)
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"the sharded-serving sweep needs >= 4 "
+            f"{jax.default_backend()} devices, found {jax.device_count()}")
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, os.path.abspath(__file__)]
     if quick:
         cmd.append("--quick")

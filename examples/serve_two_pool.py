@@ -52,10 +52,10 @@ def main():
 
     mesh = None
     if args.tp > 1 or args.mesh:
-        from repro.launch.mesh import make_smoke_mesh
+        from repro.launch.mesh import make_mesh, make_smoke_mesh
         if args.mesh:
             d, m = (int(x) for x in args.mesh.split("x"))
-            mesh = jax.make_mesh((d, m), ("data", "model"))
+            mesh = make_mesh((d, m), ("data", "model"))
         else:
             mesh = make_smoke_mesh()
 

@@ -26,7 +26,7 @@ DEFAULT_BLOCK_S = 512
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, active_ref, o_ref,
+def _decode_kernel(act_ref, q_ref, k_ref, v_ref, valid_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale: float, blocks: int):
     sb = pl.program_id(2)
 
@@ -39,24 +39,24 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, active_ref, o_ref,
     # Continuous-batching mask: rows whose slot is mid-prefill or empty
     # skip the whole KV sweep — no flops spent, and the finalize below
     # emits exact zeros for them (the engine ignores those rows).
-    active = active_ref[0] != 0
+    active = act_ref[pl.program_id(0)] != 0
 
     @pl.when(active)
     def _sweep():
         q = q_ref[0, 0]                    # (qpk, hd)
         k = k_ref[0, 0]                    # (blk, hd)
         v = v_ref[0, 0]                    # (blk, hd)
-        valid = valid_ref[0]               # (blk,)
+        valid = valid_ref[0] != 0          # (1, blk)
 
         s = jnp.dot(q.astype(jnp.float32), k.astype(jnp.float32).T,
                     preferred_element_type=jnp.float32) * scale
-        s = jnp.where(valid[None, :], s, NEG_INF)          # (qpk, blk)
+        s = jnp.where(valid, s, NEG_INF)                   # (qpk, blk)
 
         m_prev = m_ref[...]                                # (qpk,)
         m_new = jnp.maximum(m_prev, s.max(axis=1))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])                    # (qpk, blk)
-        p = jnp.where(valid[None, :], p, 0.0)
+        p = jnp.where(valid, p, 0.0)
         l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + \
             jnp.dot(p, v.astype(jnp.float32),
@@ -70,49 +70,64 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, active_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def gqa_decode(q, k_cache, v_cache, valid, active=None,
-               block_s: int = DEFAULT_BLOCK_S, interpret: bool = True):
+def gqa_decode(q, k_cache, v_cache, valid, active=None, *,
+               block_s: int = DEFAULT_BLOCK_S, interpret: bool):
     """q: (B, H, hd); k_cache/v_cache: (B, S, Hkv, hd); valid: (B, S)
     bool; active: optional (B,) bool — rows with active=False skip the
     KV sweep entirely and return zeros (continuous-batching no-op rows).
     Returns (B, H*hd). ``interpret=True`` runs the kernel body in
-    Python on CPU (validation mode); on TPU pass interpret=False."""
+    Python (CPU validation); ``interpret=False`` lowers it for the TPU.
+
+    A context that is not a multiple of ``block_s`` is padded up to one
+    with invalid positions (the padding fuses into the head-major
+    transpose copy); the ``valid`` mask rides as int32 (B, 1, S) so its
+    block's last two dims meet the TPU's (8, 128) tiling rule."""
     b, h, hd = q.shape
     s_max, hkv = k_cache.shape[1], k_cache.shape[2]
     qpk = h // hkv
     block_s = min(block_s, s_max)
-    assert s_max % block_s == 0, (s_max, block_s)
-    blocks = s_max // block_s
+    pad = -s_max % block_s
+    blocks = (s_max + pad) // block_s
     scale = 1.0 / math.sqrt(hd)
 
     qg = q.reshape(b, hkv, qpk, hd)
     kt = jnp.swapaxes(k_cache, 1, 2)       # (B, Hkv, S, hd)
     vt = jnp.swapaxes(v_cache, 1, 2)
+    vm = valid.astype(jnp.int32).reshape(b, 1, s_max)
+    if pad:
+        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        vm = jnp.pad(vm, ((0, 0), (0, 0), (0, pad)))
     if active is None:
         act = jnp.ones((b,), jnp.int32)
     else:
         act = active.astype(jnp.int32)
 
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, scale=scale, blocks=blocks),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,             # active
         grid=(b, hkv, blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, qpk, hd), lambda b_, h_, s_: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, qpk, hd),
+                         lambda b_, h_, s_, act_: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, block_s, hd),
-                         lambda b_, h_, s_: (b_, h_, s_, 0)),
+                         lambda b_, h_, s_, act_: (b_, h_, s_, 0)),
             pl.BlockSpec((1, 1, block_s, hd),
-                         lambda b_, h_, s_: (b_, h_, s_, 0)),
-            pl.BlockSpec((1, block_s), lambda b_, h_, s_: (b_, s_)),
-            pl.BlockSpec((1,), lambda b_, h_, s_: (b_,)),
+                         lambda b_, h_, s_, act_: (b_, h_, s_, 0)),
+            pl.BlockSpec((1, 1, block_s),
+                         lambda b_, h_, s_, act_: (b_, 0, s_)),
         ],
         out_specs=pl.BlockSpec((1, 1, qpk, hd),
-                               lambda b_, h_, s_: (b_, h_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, qpk, hd), q.dtype),
+                               lambda b_, h_, s_, act_: (b_, h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((qpk,), jnp.float32),
             pltpu.VMEM((qpk,), jnp.float32),
             pltpu.VMEM((qpk, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, blocks=blocks),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, qpk, hd), q.dtype),
         interpret=interpret,
-    )(qg, kt, vt, valid, act)
+    )(act, qg, kt, vt, vm)
     return out.reshape(b, h * hd)

@@ -1,9 +1,12 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this container (CPU) the kernels run in interpret mode (the kernel
-body executes in Python — numerics identical to TPU lowering at f32
-accumulation). ``repro.kernels.ops.INTERPRET`` flips to False on real
-TPU hardware.
+Each call decides how its kernel runs from the platform JAX is using
+at that moment (nothing is decided at import): on a TPU the kernel is
+lowered and compiled for the chip; on the CPU — and only when the CPU
+was asked for explicitly, ``JAX_PLATFORMS=cpu`` — the kernel body runs
+in Pallas interpret mode, which is how the tests validate it. Any
+other platform, or a CPU that JAX fell back to because no accelerator
+was found, raises instead of quietly running the interpreter.
 """
 from __future__ import annotations
 
@@ -15,7 +18,19 @@ from repro.kernels.gqa_decode import gqa_decode as _gqa_pallas
 from repro.kernels.paged_decode import paged_gqa_decode as _paged_pallas
 from repro.kernels.textrank import textrank_pallas
 
-INTERPRET = jax.default_backend() != "tpu"
+
+def interpret_mode() -> bool:
+    """False on a TPU (compiled kernel), True on an explicitly chosen
+    CPU (interpret mode); raises on anything else."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu" and jax.config.jax_platforms == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels need a TPU, or JAX_PLATFORMS=cpu for interpret "
+        f"mode; JAX is on {platform!r} with JAX_PLATFORMS="
+        f"{jax.config.jax_platforms!r}")
 
 
 def gqa_decode(q, k_cache, v_cache, valid, active=None, block_s: int = 512):
@@ -23,7 +38,7 @@ def gqa_decode(q, k_cache, v_cache, valid, active=None, block_s: int = 512):
     (B,) bool masks out continuous-batching rows that carry no live
     decode this step (their output is exactly zero)."""
     return _gqa_pallas(q, k_cache, v_cache, valid, active, block_s=block_s,
-                       interpret=INTERPRET)
+                       interpret=interpret_mode())
 
 
 def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
@@ -31,7 +46,7 @@ def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
     """Paged flash-decode attention over a block-table-indexed KV pool;
     see kernels/paged_decode.py. Inactive rows return exact zeros."""
     return _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens,
-                         active, interpret=INTERPRET)
+                         active, interpret=interpret_mode())
 
 
 def textrank_scores(sim: np.ndarray, damping: float = 0.85,
@@ -46,5 +61,5 @@ def textrank_scores(sim: np.ndarray, damping: float = 0.85,
     padded = jnp.zeros((n_pad, n_pad), jnp.float32)
     padded = padded.at[:n, :n].set(jnp.asarray(sim, jnp.float32))
     p = textrank_pallas(padded, jnp.int32(n), damping=damping, iters=iters,
-                        interpret=INTERPRET)
+                        interpret=interpret_mode())
     return np.asarray(p[:n])

@@ -85,14 +85,14 @@ def _paged_decode_kernel(bt_ref, sl_ref, act_ref, q_ref, k_ref, v_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
-                     active=None, interpret: bool = True):
+                     active=None, *, interpret: bool):
     """q: (B, H, hd); k_pages/v_pages: (P, BLOCK_S, Hkv, hd) shared
     physical block pool (token-major, the cache layout); block_tables:
     (B, NB) int32 logical->physical block map; seq_lens: (B,) int32
     valid tokens per slot (pos + 1); active: optional (B,) bool — rows
     with active=False skip the sweep entirely and return zeros.
     Returns (B, H*hd). ``interpret=True`` runs the kernel body in
-    Python on CPU (validation mode); on TPU pass interpret=False."""
+    Python (CPU validation); ``interpret=False`` lowers it for the TPU."""
     b, h, hd = q.shape
     p_blocks, block_s, hkv = k_pages.shape[0], k_pages.shape[1], \
         k_pages.shape[2]

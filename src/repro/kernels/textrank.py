@@ -47,7 +47,7 @@ def _textrank_kernel(sim_ref, n_ref, p_ref, *, damping: float, iters: int,
 @functools.partial(jax.jit,
                    static_argnames=("damping", "iters", "interpret"))
 def textrank_pallas(sim_padded, n_real, damping: float = 0.85,
-                    iters: int = 30, interpret: bool = True):
+                    iters: int = 30, *, interpret: bool):
     """sim_padded: (Np, Np) with Np % 128 == 0; n_real: () int32 actual
     sentence count. Returns the (Np,) PageRank vector (zeros in pad)."""
     n_pad = sim_padded.shape[0]

@@ -112,15 +112,6 @@ def depth_variants(cfg):
     raise ValueError(fam)
 
 
-def _cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() returns [dict] on jax < 0.5 and a plain
-    dict on newer releases; normalize to a dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def cost_one(cfg, shape, ctx) -> dict:
     """Compile one (possibly reduced-depth) variant with unrolled scans
     and return {flops, bytes, transcendentals, collectives}."""
@@ -133,7 +124,7 @@ def cost_one(cfg, shape, ctx) -> dict:
         compiled = jitted.lower(*args).compile()
     finally:
         M.SCAN_UNROLL = 1
-    ca = _cost_analysis(compiled)
+    ca = compiled.cost_analysis() or {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),
             "transcendentals": float(ca.get("transcendentals", 0.0)),
@@ -226,7 +217,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         t1 = time.time()
         compiled = lowered.compile()
         record["compile_s"] = round(time.time() - t1, 1)
-        ca = _cost_analysis(compiled)
+        ca = compiled.cost_analysis() or {}
         record["cost_analysis"] = {
             k: float(v) for k, v in ca.items()
             if isinstance(v, (int, float)) and k in

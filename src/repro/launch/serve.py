@@ -31,15 +31,20 @@ import jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.distributed import sharding as SH
 from repro.distributed.context import make_context
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 
 
 def build_fleet_runtime(args):
     """Plan K pools from the workload CDF and make the plan
     executable: one InferenceEngine per pool behind the C&R gateway
-    (serving/pools.py), boundaries scaled down to the reduced model's
-    cache so the demo runs on CPU in seconds. All serving knobs travel
-    as ONE ServingConfig (DESIGN.md §Serving API)."""
+    (serving/pools.py). The plan's token boundaries are scaled so the
+    top pool's context is ``args.top_context`` tokens; the defaults
+    (512-token top pool, 2 slots per pool, 16-token chunks) size the
+    CPU demo, and a chip run passes its own sizes through the same
+    flags. All serving knobs travel as ONE ServingConfig (DESIGN.md
+    §Serving API)."""
     from repro.core.planner import plan_k_pool
     from repro.core.workload import get_workload
     from repro.serving.config import ServingConfig
@@ -52,11 +57,8 @@ def build_fleet_runtime(args):
         print(f"  {pp.name}: c_max={pp.c_max} n_gpus={pp.n_gpus} "
               f"rho={pp.utilization:.3f} ttft_p99={pp.ttft_p99_s*1e3:.0f}ms")
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
-    c_chunk = 16
+    cfg = model_config(args)
+    params = init_params(cfg)
     mesh = None
     if args.tp > 1:
         # tp>1 shards every pool engine over a tp-device submesh
@@ -65,21 +67,47 @@ def build_fleet_runtime(args):
         from repro.launch.mesh import make_smoke_mesh
         if args.mesh:
             d, m = (int(x) for x in args.mesh.split("x"))
-            mesh = jax.make_mesh((d, m), ("data", "model"))
+            mesh = make_mesh((d, m), ("data", "model"))
         else:
             mesh = make_smoke_mesh()
     scfg = ServingConfig(
         paged=args.paged or args.prefix_cache or args.preemption,
         prefix_cache=args.prefix_cache, decode_k=args.decode_k,
         spec_k=args.spec_k, mesh=mesh, tp_degree=args.tp,
+        decode_impl=args.decode_impl,
         preemption=args.preemption, max_queue_wait=args.max_queue_wait,
         autoscale=getattr(args, "autoscale", False))
-    # scale datacenter-token boundaries onto the demo model's cache
-    rt = FleetRuntime.from_plan(cfg, params, plan, slots_per_pool=2,
-                                c_chunk=c_chunk,
-                                ctx_scale=512 / plan.pools[-1].c_max,
-                                config=scfg)
+    slots = [int(x) for x in str(args.slots).split(",")]
+    rt = FleetRuntime.from_plan(
+        cfg, params, plan,
+        slots_per_pool=slots[0] if len(slots) == 1 else slots,
+        c_chunk=args.c_chunk,
+        ctx_scale=args.top_context / plan.pools[-1].c_max, config=scfg)
+    for name, eng in rt.engines.items():
+        print(f"  {name} engine: n_max={eng.n_max} c_max={eng.c_max} "
+              f"decode={eng.decode_impl} paged={eng.paged} "
+              f"devices={[d.id for d in eng.devices()]}")
     return rt, plan
+
+
+def model_config(args):
+    """The served model: ``--reduced`` is the 2-layer float32 CPU toy;
+    otherwise the published config, with ``--layers N`` cutting only
+    its depth (the cut layers would sit on further pipeline stages)."""
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
+    if args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
+    return cfg
+
+
+def init_params(cfg):
+    """Random weights from seed 0, drawn inside one jit so that only
+    the config's dtype reaches device memory (an eager init would
+    materialize every float32 draw before its cast)."""
+    return jax.jit(M.init_params, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
 
 
 def serve_fleet(args) -> None:
@@ -178,7 +206,8 @@ def serve_fleet(args) -> None:
                   f"mu={snap['service_rate_per_iter']:.3f}/it")
 
 
-async def _http_call(host, port, method, path, body=None):
+async def http_call(host, port, method, path, body=None,
+                     timeout: float = 120.0):
     """Minimal raw HTTP/1.1 client (stdlib only) for the in-process
     smoke: returns (status, header dict, body bytes). The gateway
     always closes the connection, so read-to-EOF is the framing."""
@@ -189,7 +218,7 @@ async def _http_call(host, port, method, path, body=None):
                  f"Content-Length: {len(payload)}\r\n\r\n"
                  .encode() + payload)
     await writer.drain()
-    raw = await asyncio.wait_for(reader.read(), timeout=120.0)
+    raw = await asyncio.wait_for(reader.read(), timeout=timeout)
     writer.close()
     head, _, rest = raw.partition(b"\r\n\r\n")
     lines = head.decode("latin-1").split("\r\n")
@@ -202,7 +231,7 @@ async def _http_call(host, port, method, path, body=None):
     return status, headers, rest
 
 
-def _parse_sse(body: bytes):
+def parse_sse(body: bytes):
     """data: events -> (list of JSON chunks, saw [DONE])."""
     import json
     chunks, done = [], False
@@ -227,7 +256,7 @@ async def _smoke_client(gw) -> None:
     host, port = gw.host, gw.port
     prompt = "smoke fleet serving demo " * 6
 
-    status, _, body = await _http_call(host, port, "GET", "/health")
+    status, _, body = await http_call(host, port, "GET", "/health")
     h = json.loads(body)
     assert status == 200 and h["status"] == "ok", (status, h)
     print(f"smoke /health ok: pools={list(h['pools'])} "
@@ -235,11 +264,11 @@ async def _smoke_client(gw) -> None:
 
     req = json.dumps({"prompt": prompt, "max_tokens": 12,
                       "stream": True}).encode()
-    status, headers, body = await _http_call(host, port, "POST",
-                                             "/v1/completions", req)
+    status, headers, body = await http_call(host, port, "POST",
+                                            "/v1/completions", req)
     assert status == 200, body[:200]
     assert headers.get("content-type") == "text/event-stream", headers
-    chunks, done = _parse_sse(body)
+    chunks, done = parse_sse(body)
     token_chunks = [c for c in chunks
                     if c["choices"][0]["finish_reason"] is None]
     streamed = [t for c in token_chunks
@@ -254,14 +283,14 @@ async def _smoke_client(gw) -> None:
     # same prompt through the non-streaming path: decode is
     # deterministic argmax, so the ids must match bitwise
     req = json.dumps({"prompt": prompt, "max_tokens": 12}).encode()
-    status, _, body = await _http_call(host, port, "POST",
-                                       "/v1/completions", req)
+    status, _, body = await http_call(host, port, "POST",
+                                      "/v1/completions", req)
     offline = json.loads(body)["choices"][0]["token_ids"]
     assert status == 200 and offline == streamed, (streamed, offline)
     print("smoke parity ok: streamed ids == offline drain ids")
 
-    status, _, body = await _http_call(host, port, "POST",
-                                       "/v1/completions", b"{not json")
+    status, _, body = await http_call(host, port, "POST",
+                                      "/v1/completions", b"{not json")
     err = json.loads(body)
     assert status == 400 and err["error"]["type"] \
         == "invalid_request_error", (status, err)
@@ -271,12 +300,12 @@ async def _smoke_client(gw) -> None:
     for i in range(6):
         req = json.dumps({"prompt": f"short {i} " * 3,
                           "max_tokens": 8}).encode()
-        status, _, _ = await _http_call(host, port, "POST",
-                                        "/v1/completions", req)
+        status, _, _ = await http_call(host, port, "POST",
+                                       "/v1/completions", req)
         assert status == 200
     b_before = list(gw.runtime.router.boundaries)
-    status, _, body = await _http_call(host, port, "POST",
-                                       "/admin/replan")
+    status, _, body = await http_call(host, port, "POST",
+                                      "/admin/replan")
     rep = json.loads(body)
     assert status == 200 and rep["tick"] >= 1, rep
     assert rep["applied"], f"re-plan did not move boundaries: {rep}"
@@ -287,7 +316,7 @@ async def _smoke_client(gw) -> None:
     print(f"smoke re-plan ok: boundaries {b_before} -> {b_after} "
           f"(reason: {rep['reason']})")
 
-    status, _, body = await _http_call(host, port, "GET", "/metrics")
+    status, _, body = await http_call(host, port, "GET", "/metrics")
     text = body.decode()
     assert status == 200
     sample_re = re.compile(
@@ -325,8 +354,8 @@ async def _chaos_client(gw) -> None:
     # unfaulted reference: which pool serves this prompt + its tokens
     req = json.dumps({"prompt": prompt,
                       "max_tokens": max_tokens}).encode()
-    status, _, body = await _http_call(host, port, "POST",
-                                       "/v1/completions", req)
+    status, _, body = await http_call(host, port, "POST",
+                                      "/v1/completions", req)
     ref = json.loads(body)
     assert status == 200, body[:200]
     ref_ids = ref["choices"][0]["token_ids"]
@@ -336,7 +365,7 @@ async def _chaos_client(gw) -> None:
     sreq = json.dumps({"prompt": prompt, "max_tokens": max_tokens,
                        "stream": True}).encode()
     stream_task = asyncio.ensure_future(
-        _http_call(host, port, "POST", "/v1/completions", sreq))
+        http_call(host, port, "POST", "/v1/completions", sreq))
     tok0, done0 = gw.tokens_streamed, gw.completions_done
     for _ in range(20_000):
         if gw.tokens_streamed > tok0:
@@ -356,8 +385,8 @@ async def _chaos_client(gw) -> None:
         await asyncio.sleep(0.001)
     assert gw.runtime.reprovision_stats["engine_restarts"] >= 1, \
         "driver never recovered the killed engine"
-    status, headers, body = await _http_call(host, port, "POST",
-                                             "/v1/completions", req)
+    status, headers, body = await http_call(host, port, "POST",
+                                            "/v1/completions", req)
     assert status == 503, (status, body[:200])
     retry_after = int(headers["retry-after"])
     assert retry_after >= 1, headers
@@ -368,7 +397,7 @@ async def _chaos_client(gw) -> None:
     # the killed stream must still deliver EVERY token, bitwise
     status, _, body = await stream_task
     assert status == 200, body[:200]
-    chunks, done = _parse_sse(body)
+    chunks, done = parse_sse(body)
     streamed = [t for c in chunks
                 if c["choices"][0]["finish_reason"] is None
                 for t in c["choices"][0]["token_ids"]]
@@ -380,14 +409,14 @@ async def _chaos_client(gw) -> None:
 
     # after the blackout the pool serves again — same tokens
     await asyncio.sleep(retry_after)
-    status, _, body = await _http_call(host, port, "POST",
-                                       "/v1/completions", req)
+    status, _, body = await http_call(host, port, "POST",
+                                      "/v1/completions", req)
     assert status == 200, (status, body[:200])
     retry_ids = json.loads(body)["choices"][0]["token_ids"]
     assert retry_ids == ref_ids, (retry_ids, ref_ids)
     print("chaos: post-blackout retry ok (tokens bitwise identical)")
 
-    status, _, body = await _http_call(host, port, "GET", "/metrics")
+    status, _, body = await http_call(host, port, "GET", "/metrics")
     text = body.decode()
     for needle in ("fleetopt_engine_restarts_total",
                    "fleetopt_migrated_requests_total"):
@@ -449,10 +478,13 @@ def serve_http(args) -> None:
         pass
 
 
-def main():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minitron-8b")
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0, metavar="N",
+                    help="serve only the first N layers of --arch "
+                         "(0 = its published depth); widths stay")
     ap.add_argument("--mesh", default="")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=64)
@@ -467,6 +499,23 @@ def main():
                     help="workload CDF for --fleet planning")
     ap.add_argument("--lam", type=float, default=1000.0,
                     help="arrival rate (req/s) for --fleet planning")
+    ap.add_argument("--slots", default="2", metavar="N[,N...]",
+                    help="--fleet engine slots per pool (capped by the "
+                         "plan's per-GPU slot count): one number for "
+                         "every pool, or one per pool")
+    ap.add_argument("--top-context", type=int, default=512,
+                    metavar="TOKENS",
+                    help="--fleet top pool's context; every planned "
+                         "boundary scales by TOKENS / the plan's top "
+                         "context (default 512: the CPU demo)")
+    ap.add_argument("--c-chunk", type=int, default=16, metavar="TOKENS",
+                    help="--fleet engines' prefill chunk length")
+    ap.add_argument("--decode-impl", default="xla",
+                    choices=["xla", "pallas"],
+                    help="--fleet engines' decode attention: the XLA "
+                         "gather path, or the Pallas paged/flash "
+                         "kernel (one device per engine: not with "
+                         "--tp > 1)")
     ap.add_argument("--paged", action="store_true",
                     help="--fleet engines use the paged KV cache "
                          "(block-table allocator; same output tokens)")
@@ -531,7 +580,12 @@ def main():
                     metavar="SECONDS",
                     help="run a re-planner tick every S seconds "
                          "(--http mode; /admin/replan always works)")
-    args = ap.parse_args()
+    return ap.parse_args(argv)
+
+
+def main():
+    args = parse_args()
+    enable_compile_cache()
 
     if args.fleet:
         if args.http is not None or args.smoke:
@@ -540,21 +594,19 @@ def main():
             serve_fleet(args)
         return
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = dataclasses.replace(cfg.reduced(), dtype="float32")
+    cfg = model_config(args)
     if args.int8_kv:
         cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
     else:
         d, m = jax.device_count(), 1
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     ctx = make_context(mesh)
     cache_len = args.prompt_len + args.new_tokens
 
     key = jax.random.PRNGKey(0)
-    params = M.init_params(cfg, key)
+    params = init_params(cfg)
     toks = jax.random.randint(key, (args.batch, args.prompt_len), 1,
                               cfg.vocab_size)
     batch = {"tokens": toks}
@@ -575,15 +627,14 @@ def main():
         return M.decode_step(params, cfg, tok, cache, pos, parallel=ctx)
 
     t0 = time.time()
-    with jax.set_mesh(mesh):
-        logits, cache = jax.jit(prefill)(params, batch)
+    logits, cache = jax.jit(prefill)(params, batch)
+    nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+    out = [nxt]
+    dstep = jax.jit(decode)
+    for t in range(args.new_tokens - 1):
+        logits, cache = dstep(params, nxt, cache, args.prompt_len + t)
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-        out = [nxt]
-        dstep = jax.jit(decode)
-        for t in range(args.new_tokens - 1):
-            logits, cache = dstep(params, nxt, cache, args.prompt_len + t)
-            nxt = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-            out.append(nxt)
+        out.append(nxt)
     out = jnp.concatenate(out, axis=1)
     dt = time.time() - t0
     print(f"arch={cfg.name} mesh {d}x{m} batch={args.batch} "

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.distributed import sharding as SH
 from repro.distributed.context import make_context
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 from repro.training import checkpoint as CKPT
 from repro.training.data import DataConfig, batch_at
@@ -51,7 +52,7 @@ def main():
         d, m = (int(x) for x in args.mesh.split("x"))
     else:
         d, m = jax.device_count(), 1
-    mesh = jax.make_mesh((d, m), ("data", "model"))
+    mesh = make_mesh((d, m), ("data", "model"))
     ctx = make_context(mesh)
     print(f"mesh {d}x{m} ({jax.device_count()} devices), arch={cfg.name}")
 

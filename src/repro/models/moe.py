@@ -76,11 +76,8 @@ def moe_block_sharded(p: Dict, cfg: ModelConfig, x, parallel,
     The shared experts (DeepSeek/Llama-4) are replicated over the model
     axis and computed on local tokens either way.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:          # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
 
     mx = parallel.model_axis
     p_specs = {"router": P(None, None),

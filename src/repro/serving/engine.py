@@ -73,7 +73,9 @@ at most ``block_size - 1`` suffix tokens).
 Both jitted step functions DONATE the cache pytree (donate_argnums):
 without donation XLA keeps the input and output cache alive across
 every step — a 2x HBM tax on exactly the resource this engine
-economizes. (CPU ignores donation; on TPU the buffer is reused.)
+economizes. The CPU and TPU backends both honour it: a cache array
+handed to a step is deleted, so host reads (cache_row, swap-out,
+migration) always go through the current ``self.cache``.
 
 The engine is MESH-AWARE (DESIGN.md §Sharded serving): pass ``mesh``
 (usually one ``launch/mesh.make_submeshes`` replica submesh) and every
@@ -89,9 +91,8 @@ the replicated NamedSharding; step outputs are pinned to the cache
 shardings with with_sharding_constraint so donation reuses the sharded
 buffers. Output tokens are pinned bitwise against the 1-device engine
 (tests/test_decode_consistency.py, host-platform mesh). The Pallas
-decode kernels are single-device programs — a sharded engine serves
-through the XLA reference path instead (``pallas_fallback``; kernel
-shard_map integration is out of scope).
+decode kernels are single-device programs — a sharded engine must be
+built with ``decode_impl="xla"`` and refuses ``"pallas"``.
 
 The engine is functional at the device boundary: all device state lives
 in ``self.cache`` (a pytree) and is updated by jit'd steps. Slot and
@@ -244,17 +245,14 @@ class InferenceEngine:
             if mesh is not None else None
         self.tp_degree = int(mesh.shape[self.parallel.model_axis]) \
             if mesh is not None else 1
-        self.pallas_fallback = False
         if mesh is not None and decode_impl == "pallas":
             # The Pallas decode kernels are single-device programs;
             # driving them over a mesh-sharded cache needs a shard_map
-            # integration that is explicitly out of scope. A sharded
-            # engine serves through the XLA reference path (bitwise-
-            # pinned against Pallas on one device by the PR-5 tests).
-            decode_impl = "xla"
-            self.pallas_fallback = True
-        assert mesh is None or decode_impl != "pallas", \
-            "sharded engine must not reach the Pallas kernels"
+            # integration that does not exist yet. A sharded engine
+            # must be asked for the XLA path (decode_impl="xla").
+            raise NotImplementedError(
+                "decode_impl='pallas' runs on one device only; a "
+                "mesh-sharded engine needs decode_impl='xla'")
         self.decode_impl = decode_impl
         if mesh is not None:
             # replicated NamedSharding for scheduler-state uploads
@@ -675,17 +673,11 @@ class InferenceEngine:
         decode <= 1, decode_scan <= 1 (its K is baked in at
         construction), and prefill/mixed <= len(self.buckets) each
         (the bucketed chunk shape selects the trace)."""
-        def size(fn, fallback):
-            try:
-                return int(fn._cache_size())
-            except AttributeError:       # older jax: host-side tracking
-                return fallback
         return {
-            "decode": size(self._decode, 1),
-            "decode_scan": size(self._decode_scan, 1),
-            "prefill": size(self._prefill_step,
-                            len(self.prefill_buckets_used)),
-            "mixed": size(self._mixed, len(self.prefill_buckets_used)),
+            "decode": self._decode._cache_size(),
+            "decode_scan": self._decode_scan._cache_size(),
+            "prefill": self._prefill_step._cache_size(),
+            "mixed": self._mixed._cache_size(),
         }
 
     def cache_row(self, s: int):

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Union
 
 from repro.configs.base import ModelConfig
 from repro.core.compression import ExtractiveCompressor
@@ -171,7 +171,8 @@ class FleetRuntime:
 
     @classmethod
     def from_plan(cls, cfg: ModelConfig, params, plan: FleetPlan,
-                  slots_per_pool: int = 4, c_chunk: int = 64,
+                  slots_per_pool: Union[int, Sequence[int]] = 4,
+                  c_chunk: int = 64,
                   ctx_scale: Optional[float] = None, *,
                   config: Optional[ServingConfig] = None,
                   lout_predictor: Optional[OutputLenPredictor] = None,
@@ -180,7 +181,8 @@ class FleetRuntime:
 
         The plan's per-GPU slot counts target datacenter hardware; a
         local runtime caps each pool at ``slots_per_pool`` engine
-        slots.  ``ctx_scale`` shrinks the token boundaries (e.g.
+        slots (one cap for every pool, or one per pool).
+        ``ctx_scale`` shrinks the token boundaries (e.g.
         ``512 / 65536`` to demo a 64K plan on a reduced model with a
         512-token cache); boundaries are kept >= 2 * c_chunk so the
         chunked prefill path stays exercised.  Serving knobs come from
@@ -195,8 +197,13 @@ class FleetRuntime:
         c_top = max(int(plan.pools[-1].c_max * scale),
                     (bounds[-1] if bounds else 2 * c_chunk) * 2)
         c_maxes = tuple(bounds) + (c_top,)
-        n_maxes = tuple(min(slots_per_pool, max(1, pp.n_max))
-                        for pp in plan.pools)
+        caps = ([slots_per_pool] * len(plan.pools)
+                if isinstance(slots_per_pool, int) else list(slots_per_pool))
+        if len(caps) != len(plan.pools):
+            raise ValueError(f"{len(caps)} slot caps for "
+                             f"{len(plan.pools)} pools")
+        n_maxes = tuple(min(cap, max(1, pp.n_max))
+                        for cap, pp in zip(caps, plan.pools))
         rt = cls(cfg, params, tuple(bounds), plan.gammas, n_maxes,
                  c_maxes, c_chunk, config=config,
                  lout_predictor=lout_predictor, **overrides)

@@ -10,57 +10,6 @@ import dataclasses  # noqa: E402
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-# ---------------------------------------------------------------------------
-# hypothesis shim: the seed container has no network access and no
-# `hypothesis` wheel. Property tests degrade to skips; the deterministic
-# tests in the same modules still collect and run.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:  # pragma: no cover - exercised only on offline images
-    import types
-
-    def _given(*_a, **_kw):
-        def deco(fn):
-            # NOTE: deliberately no functools.wraps — pytest would read
-            # the wrapped signature and try to inject the strategy
-            # kwargs as fixtures. A bare zero-arg skipper collects fine.
-            def skipper():
-                pytest.skip("hypothesis not installed (offline image)")
-            skipper.__name__ = fn.__name__
-            skipper.__doc__ = fn.__doc__
-            return skipper
-        return deco
-
-    def _settings(*_a, **_kw):
-        def deco(fn):
-            return fn
-        return deco
-
-    class _Strategy:
-        """Inert placeholder for hypothesis strategies."""
-
-        def __init__(self, name):
-            self._name = name
-
-        def __call__(self, *a, **kw):
-            return self
-
-        def __getattr__(self, item):
-            return _Strategy(f"{self._name}.{item}")
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.__getattr__ = lambda name: _Strategy(name)  # type: ignore[attr-defined]
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    _hyp.__stub__ = True
-
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
 from repro.configs.base import get_config  # noqa: E402
 
 
@@ -74,3 +23,15 @@ def reduced_f32(name: str, **overrides):
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     return cfg
+
+
+def mid_stream_eos(outputs) -> int:
+    """A token some request emits mid-stream, at its first occurrence:
+    used as EOS it stops that request early whatever numbers the
+    installed jax draws for the model (the stream's own tokens choose
+    it, not a pinned seed)."""
+    for out in outputs:
+        for j in range(1, len(out) - 1):
+            if out[j] not in out[:j]:
+                return out[j]
+    raise AssertionError("no request emitted 3 or more tokens")

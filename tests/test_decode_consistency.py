@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import reduced_f32
+from conftest import mid_stream_eos, reduced_f32
 from repro.models import model as M
 from repro.serving.engine import InferenceEngine, ServeRequest
 
@@ -94,9 +94,9 @@ def _stream(seed=42, n_req=6, max_new=12, l_in_max=40):
     return reqs
 
 
-def _run_engine(cfg, params, reqs, **kw):
+def _run_engine(cfg, params, reqs, eos_id=EOS, **kw):
     eng = InferenceEngine(cfg, params, n_max=3, c_max=128, c_chunk=16,
-                          eos_id=EOS, **kw)
+                          eos_id=eos_id, **kw)
     for r in reqs:
         eng.submit(ServeRequest(**r))
     res = eng.run_to_completion(5000)
@@ -145,13 +145,15 @@ def test_eos_terminates_mid_scan(engine_model):
     K=1, and the KV slot frees for the next admission."""
     cfg, params = engine_model
     reqs = _stream(seed=5, n_req=8, max_new=20)
-    base, _ = _run_engine(cfg, params, reqs, decode_k=1)
-    got, eng = _run_engine(cfg, params, reqs, decode_k=8)
+    free, _ = _run_engine(cfg, params, reqs, eos_id=None, decode_k=1)
+    eos = mid_stream_eos(free.values())
+    base, _ = _run_engine(cfg, params, reqs, eos_id=eos, decode_k=1)
+    got, eng = _run_engine(cfg, params, reqs, eos_id=eos, decode_k=8)
     assert got == base
-    # the fixed stream really exercises EOS mid-stream (seed-pinned)
-    assert any(out and out[-1] == EOS and len(out) < r["max_new_tokens"]
+    # the stream really exercises EOS mid-stream
+    assert any(out and out[-1] == eos and len(out) < r["max_new_tokens"]
                for r, out in zip(reqs, base.values())), \
-        "stream no longer hits EOS early; change the seed"
+        "stream no longer hits EOS early"
     assert not eng.busy()
 
 
@@ -292,16 +294,31 @@ def test_sharded_cache_is_actually_sharded(engine_model):
 
 @multi_device
 def test_sharded_pallas_falls_back_to_xla(engine_model):
-    """decode_impl='pallas' on a mesh engine must take the documented
-    XLA fallback (the kernel's block specs assume an unsharded cache)
-    — and still match the 1-device Pallas engine's tokens."""
+    """decode_impl='pallas' on a tp=4 mesh engine raises instead of
+    quietly serving through XLA (the kernels' block specs assume an
+    unsharded cache); the same engine asked for 'xla' matches the
+    1-device Pallas engine's tokens."""
     cfg, params = engine_model
     reqs = _stream(n_req=3, max_new=6)
+    with pytest.raises(NotImplementedError, match="decode_impl='xla'"):
+        _run_engine(cfg, params, reqs, decode_impl="pallas",
+                    mesh=_tp_mesh())
     base, _ = _run_engine(cfg, params, reqs, decode_impl="pallas")
-    got, eng = _run_engine(cfg, params, reqs, decode_impl="pallas",
+    got, eng = _run_engine(cfg, params, reqs, decode_impl="xla",
                            mesh=_tp_mesh())
-    assert eng.pallas_fallback and eng.decode_impl == "xla"
+    assert eng.decode_impl == "xla"
     assert got == base
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_mesh_engine_refuses_pallas(engine_model, paged):
+    """Any mesh, even one device, makes the engine refuse 'pallas'."""
+    from repro.launch.mesh import make_smoke_mesh, make_submeshes
+    cfg, params = engine_model
+    mesh = make_submeshes(make_smoke_mesh(), 1)[0]
+    with pytest.raises(NotImplementedError, match="decode_impl='xla'"):
+        InferenceEngine(cfg, params, n_max=2, c_max=64, c_chunk=16,
+                        paged=paged, decode_impl="pallas", mesh=mesh)
 
 
 @multi_device

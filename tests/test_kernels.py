@@ -13,6 +13,7 @@ GQA_SHAPES = [  # (b, h, hkv, hd, s, block_s)
     (3, 16, 2, 64, 1024, 512),
     (2, 8, 8, 128, 512, 128),
     (1, 2, 1, 64, 256, 256),    # single kv head, single block
+    (2, 8, 2, 64, 600, 512),    # context not a block multiple: padded
 ]
 
 

@@ -15,6 +15,7 @@ import jax, jax.numpy as jnp
 from repro.configs.base import get_config
 from repro.models import moe as MOE
 from repro.distributed.context import ParallelContext
+from repro.launch.mesh import make_mesh
 
 for arch in ("llama4-scout-17b-a16e", "deepseek-v2-236b"):
     cfg = dataclasses.replace(get_config(arch).reduced(), dtype="float32")
@@ -22,20 +23,18 @@ for arch in ("llama4-scout-17b-a16e", "deepseek-v2-236b"):
         cfg.moe, num_experts=8, capacity_factor=8.0))
     key = jax.random.PRNGKey(0)
     p = MOE.init_moe(key, cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ctx = ParallelContext(mesh=mesh, data_axes=("data",))
     x = jax.random.normal(key, (4, 16, cfg.d_model)) * 0.5
     y_ref, _ = MOE.moe_block(p, cfg, x, None)
-    with mesh:
-        y_a2a, _ = MOE.moe_block_sharded(p, cfg, x, ctx, mode="a2a")
-        y_psum, _ = MOE.moe_block_sharded(p, cfg, x, ctx, mode="psum")
+    y_a2a, _ = MOE.moe_block_sharded(p, cfg, x, ctx, mode="a2a")
+    y_psum, _ = MOE.moe_block_sharded(p, cfg, x, ctx, mode="psum")
     for name, y in (("a2a", y_a2a), ("psum", y_psum)):
         err = float(jnp.max(jnp.abs(y_ref - y)))
         assert err < 1e-4, (arch, name, err)
     # indivisible batch falls back gracefully
     x1 = x[:1]
-    with mesh:
-        y1, _ = MOE.moe_block_sharded(p, cfg, x1, ctx, mode="psum")
+    y1, _ = MOE.moe_block_sharded(p, cfg, x1, ctx, mode="psum")
     err = float(jnp.max(jnp.abs(MOE.moe_block(p, cfg, x1, None)[0] - y1)))
     assert err < 1e-4, ("b1", err)
 print("MOE_SHARDED_OK")
@@ -47,7 +46,7 @@ print("MOE_SHARDED_OK")
 def test_moe_sharded_subprocess():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     # generous timeout: the fake-8-device compile is CPU-bound and this
     # box is cpu-share throttled, so wall time varies ~10x with ambient
     # load (48 s idle, >500 s when the suite runs around it)

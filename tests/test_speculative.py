@@ -27,7 +27,7 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import reduced_f32
+from conftest import mid_stream_eos, reduced_f32
 from repro.models import model as M
 from repro.serving.engine import InferenceEngine, ServeRequest
 from repro.serving.draft import propose_draft
@@ -63,9 +63,9 @@ def _stream(seed=42, n_req=6, max_new=16, l_in_max=40):
     return reqs
 
 
-def _run_engine(cfg, params, reqs, **kw):
+def _run_engine(cfg, params, reqs, eos_id=EOS, **kw):
     eng = InferenceEngine(cfg, params, n_max=3, c_max=128, c_chunk=16,
-                          eos_id=EOS, **kw)
+                          eos_id=eos_id, **kw)
     for r in reqs:
         eng.submit(ServeRequest(**r))
     res = eng.run_to_completion(5000)
@@ -128,12 +128,17 @@ def test_spec_eos_terminates_stream(engine_model):
     non-boundary micro-iteration must match the plain engine."""
     cfg, params = engine_model
     reqs = _stream(seed=5, n_req=8, max_new=20)
-    base, _ = _run_engine(cfg, params, reqs, decode_k=1, spec_k=1)
-    got, eng = _run_engine(cfg, params, reqs, decode_k=4, spec_k=4)
+    free, _ = _run_engine(cfg, params, reqs, eos_id=None, decode_k=1,
+                          spec_k=1)
+    eos = mid_stream_eos(free.values())
+    base, _ = _run_engine(cfg, params, reqs, eos_id=eos, decode_k=1,
+                          spec_k=1)
+    got, eng = _run_engine(cfg, params, reqs, eos_id=eos, decode_k=4,
+                           spec_k=4)
     assert got == base
-    assert any(out and out[-1] == EOS and len(out) < r["max_new_tokens"]
+    assert any(out and out[-1] == eos and len(out) < r["max_new_tokens"]
                for r, out in zip(reqs, base.values())), \
-        "stream no longer hits EOS early; change the seed"
+        "stream no longer hits EOS early"
     assert not eng.busy()
 
 
