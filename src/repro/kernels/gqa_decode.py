@@ -129,5 +129,6 @@ def gqa_decode(q, k_cache, v_cache, valid, active=None, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, qpk, hd), q.dtype),
         interpret=interpret,
+        name="gqa_decode",
     )(act, qg, kt, vt, vm)
     return out.reshape(b, h * hd)

@@ -138,5 +138,6 @@ def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, qpk, hd), q.dtype),
         interpret=interpret,
+        name="paged_gqa_decode",
     )(bt, sl, act, qg, kt, vt)
     return out.reshape(b, h * hd)

@@ -20,9 +20,14 @@ and spins up one gateway-routed engine per pool.
   # CI smoke: ephemeral port, in-process client, exit nonzero on failure
   PYTHONPATH=src python -m repro.launch.serve --fleet 2 --reduced \
       --decode-k 4 --smoke
+
+  # record program spans while serving, written as JSON lines on exit
+  PYTHONPATH=src python -m repro.launch.serve --fleet 2 --reduced \
+      --decode-k 4 --http 8000 --spans spans.jsonl
 """
 import argparse
 import dataclasses
+import json
 import time
 
 import jax
@@ -467,15 +472,34 @@ def serve_http(args) -> None:
         async with gw._server:
             await gw._server.serve_forever()
 
-    if args.smoke:
-        t0 = time.time()
-        asyncio.run(smoke())
-        print(f"serve smoke passed in {time.time() - t0:.1f}s")
-        return
+    if args.spans:
+        rt.tracer.start()
     try:
-        asyncio.run(forever())
-    except KeyboardInterrupt:
-        pass
+        if args.smoke:
+            t0 = time.time()
+            asyncio.run(smoke())
+            print(f"serve smoke passed in {time.time() - t0:.1f}s")
+            return
+        try:
+            asyncio.run(forever())
+        except KeyboardInterrupt:
+            pass
+    finally:
+        if args.spans:
+            write_spans(rt.tracer, args.spans)
+
+
+def write_spans(tracer, path: str) -> None:
+    """Stop ``tracer`` and write its spans to ``path``, one JSON object
+    a line (times in ``time.perf_counter_ns`` nanoseconds)."""
+    tracer.stop()
+    spans = tracer.take()
+    with open(path, "w") as f:
+        for sp in spans:
+            f.write(json.dumps(sp._asdict()) + "\n")
+    print(f"{len(spans)} spans written to {path}"
+          + (f" ({tracer.dropped} dropped: the list was full)"
+             if tracer.dropped else ""))
 
 
 def parse_args(argv=None):
@@ -576,6 +600,11 @@ def parse_args(argv=None):
                          "re-planner when a tick's context/GPU-count "
                          "delta exceeds its hysteresis (zero-drop KV "
                          "migration; DESIGN.md §Live re-provisioning)")
+    ap.add_argument("--spans", default=None, metavar="FILE",
+                    help="with --http or --smoke: record the gateway's, "
+                         "router's and engines' program spans (each also "
+                         "a jax.profiler annotation) and write them to "
+                         "FILE as JSON lines on exit")
     ap.add_argument("--replan-interval", type=float, default=None,
                     metavar="SECONDS",
                     help="run a re-planner tick every S seconds "

@@ -105,6 +105,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import math
+import time
 from collections import Counter, OrderedDict
 from functools import partial
 from typing import Dict, List, Optional, Set, Tuple
@@ -121,6 +122,7 @@ from repro.distributed.context import make_context
 from repro.models import model as M
 from repro.serving.config import ServingConfig
 from repro.serving.draft import propose_draft
+from repro.serving.tracing import Tracer
 
 
 def prefill_buckets(c_chunk: int, min_bucket: int = 8) -> Tuple[int, ...]:
@@ -194,7 +196,9 @@ class InferenceEngine:
 
     def __init__(self, cfg: ModelConfig, params, n_max: int, c_max: int,
                  c_chunk: Optional[int] = None, *,
-                 config: Optional[ServingConfig] = None, **overrides):
+                 config: Optional[ServingConfig] = None,
+                 name: str = "engine", tracer: Optional[Tracer] = None,
+                 **overrides):
         # -- ServingConfig shim (DESIGN.md §Serving API) -------------------
         # One validated config object replaces the legacy 16-kwarg
         # sprawl; explicit kwargs (including positional c_chunk) fold
@@ -263,6 +267,10 @@ class InferenceEngine:
             self._replicated = None
         self.cfg = cfg
         self.params = params
+        # the pool's name: it labels this engine's spans and names its
+        # step programs (``<name>_mixed`` etc.) in a profiler trace
+        self.name = name
+        self.tracer = tracer if tracer is not None else Tracer()
         self.n_max = n_max
         self.c_max = c_max
         self.c_chunk = min(c_chunk, c_max)
@@ -340,7 +348,21 @@ class InferenceEngine:
         self.results: Dict[int, ServeResult] = {}
         self.iteration = 0
         self._queue_iters: Dict[int, int] = {}
-        self._enqueued_at: Dict[int, int] = {}
+        # rid -> (iteration, perf_counter_ns) when it (re-)entered the
+        # queue
+        self._enqueued_at: Dict[int, Tuple[int, int]] = {}
+        # wall-clock queue wait, summed over admissions into a slot
+        # (/metrics, the benchmark's sched.queue_wait_ms_mean)
+        self.queue_wait_ns = 0
+        self.queue_admissions = 0
+        # head-of-queue admissions deferred because the block pool could
+        # not cover the request's worst case
+        self.admissions_deferred = 0
+        # paged KV, summed over dispatching steps: blocks reserved
+        # (allocated + worst case not yet allocated) and blocks holding
+        # written tokens
+        self.kv_reserved_block_steps = 0
+        self.kv_used_block_steps = 0
         self._prefill_iters: Dict[int, int] = {}
         # -- overload survival (DESIGN.md §Overload survival) --------------
         # preemption: when paged admission would defer, preempt the most
@@ -386,12 +408,10 @@ class InferenceEngine:
         # max_tokens; preemption is the safety net when a prediction
         # runs short (see _reservation_breach)
         self.lout_reservation = bool(scfg.lout_reservation)
-        # rolling arrival/service-rate estimate (EMA per iteration) for
-        # the stability-aware admission bound (Little's-law style)
+        # rolling service-rate estimate (EMA per iteration) for the
+        # stability-aware admission bound (Little's-law style)
         self._completed_total = 0
-        self._arrived_since_step = 0
         self._mu_hat = 0.0            # completions / iteration
-        self._lam_hat = 0.0           # offered arrivals / iteration
         self._rate_alpha = 0.05
         # buckets that actually compiled a prefill trace this lifetime
         self.prefill_buckets_used: Set[int] = set()
@@ -439,10 +459,10 @@ class InferenceEngine:
         # donate_argnums=1: the cache pytree is consumed by each step
         # and its buffer reused for the output (no 2x HBM residency)
         if paged:
-            self._decode = jax.jit(partial(self._paged_decode_fn,
-                                           decode_impl), donate_argnums=1)
-            self._prefill_step = jax.jit(self._paged_prefill_fn,
-                                         donate_argnums=1)
+            self._decode = self._program(
+                "decode", partial(self._paged_decode_fn, decode_impl), 1)
+            self._prefill_step = self._program(
+                "prefill", self._paged_prefill_fn, 1)
             # decode scan: cache + carried device state donated; the
             # block table (arg 3) is the cached _bt_device and must
             # survive the call. spec_k > 1 swaps the per-token scan
@@ -450,37 +470,50 @@ class InferenceEngine:
             # donation (the draft table args are fresh per dispatch
             # and not donated).
             if self.spec_k > 1:
-                self._decode_scan = jax.jit(
+                self._decode_scan = self._program(
+                    "decode_scan",
                     partial(self._paged_spec_scan_fn, decode_impl,
-                            self.decode_k, self.spec_k),
-                    donate_argnums=(1, 2, 4, 5, 6))
+                            self.decode_k, self.spec_k), (1, 2, 4, 5, 6))
             else:
-                self._decode_scan = jax.jit(
+                self._decode_scan = self._program(
+                    "decode_scan",
                     partial(self._paged_decode_scan_fn, decode_impl,
-                            self.decode_k),
-                    donate_argnums=(1, 2, 4, 5, 6))
-            self._mixed = jax.jit(partial(self._paged_mixed_fn,
-                                          decode_impl), donate_argnums=1)
+                            self.decode_k), (1, 2, 4, 5, 6))
+            self._mixed = self._program(
+                "mixed", partial(self._paged_mixed_fn, decode_impl), 1)
         else:
-            self._decode = jax.jit(partial(self._decode_fn, decode_impl),
-                                   donate_argnums=1)
+            self._decode = self._program(
+                "decode", partial(self._decode_fn, decode_impl), 1)
             # NOT static in chunk length: the bucketed token array's shape
             # selects the trace, so traces are bounded by len(self.buckets)
-            self._prefill_step = jax.jit(partial(self._prefill_fn,
-                                                 decode_impl),
-                                         donate_argnums=1)
+            self._prefill_step = self._program(
+                "prefill", partial(self._prefill_fn, decode_impl), 1)
             if self.spec_k > 1:
-                self._decode_scan = jax.jit(
+                self._decode_scan = self._program(
+                    "decode_scan",
                     partial(self._spec_scan_fn, decode_impl, self.decode_k,
-                            self.spec_k),
-                    donate_argnums=(1, 2, 3, 4, 5))
+                            self.spec_k), (1, 2, 3, 4, 5))
             else:
-                self._decode_scan = jax.jit(
+                self._decode_scan = self._program(
+                    "decode_scan",
                     partial(self._decode_scan_fn, decode_impl,
-                            self.decode_k),
-                    donate_argnums=(1, 2, 3, 4, 5))
-            self._mixed = jax.jit(partial(self._mixed_fn, decode_impl),
-                                  donate_argnums=1)
+                            self.decode_k), (1, 2, 3, 4, 5))
+            self._mixed = self._program(
+                "mixed", partial(self._mixed_fn, decode_impl), 1)
+
+    def _program(self, program: str, fn, donate):
+        """``fn`` jitted under a stable per-pool name: its XLA module
+        reads ``jit_<pool>_<program>`` (a profiler trace's "XLA
+        Modules" line) and its ops sit under the named scope
+        ``<pool>.<program>``."""
+        scope = f"{self.name}.{program}"
+
+        def step_program(*args):
+            with jax.named_scope(scope):
+                return fn(*args)
+        step_program.__name__ = step_program.__qualname__ = \
+            f"{self.name}_{program}"
+        return jax.jit(step_program, donate_argnums=donate)
 
     # -- mesh sharding (DESIGN.md §Sharded serving) ------------------------
     def _serving_shardings(self, abstract_init, paged: bool):
@@ -548,7 +581,6 @@ class InferenceEngine:
         queue is what keeps P99 TTFT degrading gracefully instead of
         collapsing past the stability boundary. Returns False iff
         shed (the empty result carries ``shed=True``)."""
-        self._arrived_since_step += 1
         if (self.max_queue_wait is not None and self.waiting
                 and self._completed_total > 0
                 and self.queue_wait_estimate() > self.max_queue_wait):
@@ -557,8 +589,21 @@ class InferenceEngine:
             self.overload_stats["shed"] += 1
             return False
         self.waiting.append(req)
-        self._enqueued_at[req.rid] = self.iteration
+        self._mark_queued(req.rid)
         return True
+
+    def _mark_queued(self, rid: int) -> None:
+        """``rid`` (re-)enters the queue now."""
+        self._enqueued_at[rid] = (self.iteration, time.perf_counter_ns())
+
+    def _mark_admitted(self, rid: int) -> None:
+        """``rid`` left the queue for a slot: its wait, in iterations
+        (per request) and in wall time (summed per engine)."""
+        it, ns = self._enqueued_at.pop(rid)
+        self._queue_iters[rid] = self._queue_iters.get(rid, 0) \
+            + self.iteration - it
+        self.queue_wait_ns += time.perf_counter_ns() - ns
+        self.queue_admissions += 1
 
     def busy(self) -> bool:
         return any(r is not None for r in self.slot_req) or bool(self.waiting)
@@ -589,7 +634,7 @@ class InferenceEngine:
 
     def _update_rate_estimates(self, k_iters: int, completions: int) -> None:
         """Fold one dispatch's worth of iterations into the EMA rate
-        estimates. A decode_k scan advances k iterations per call, so
+        estimate. A decode_k scan advances k iterations per call, so
         the decay compounds per ITERATION, keeping the estimate
         comparable across K."""
         if k_iters <= 0:
@@ -597,9 +642,6 @@ class InferenceEngine:
         decay = (1.0 - self._rate_alpha) ** k_iters
         self._mu_hat = decay * self._mu_hat \
             + (1.0 - decay) * (completions / k_iters)
-        self._lam_hat = decay * self._lam_hat \
-            + (1.0 - decay) * (self._arrived_since_step / k_iters)
-        self._arrived_since_step = 0
 
     def utilization_snapshot(self, detail: bool = False):
         """Mean PER-ITERATION slot occupancy since engine start.
@@ -628,7 +670,6 @@ class InferenceEngine:
                 "queue_depth": len(self.waiting),
                 "queue_wait_est_iters": self.queue_wait_estimate(),
                 "service_rate_per_iter": self._mu_hat,
-                "arrival_rate_per_iter": self._lam_hat,
                 "host_tier_blocks": self.host_tier_blocks(),
                 **self.overload_stats}
 
@@ -713,17 +754,27 @@ class InferenceEngine:
 
         The iteration clock advances by the number of model iterations
         the dispatch performed (decode_k for a scan), never by
-        dispatches."""
+        dispatches. With the tracer on, the step is the span
+        ``eng.step`` (its kind: idle / prefill / mixed / decode), with
+        ``eng.admit``, ``eng.alloc``, ``eng.dispatch``, ``eng.sync``
+        and ``eng.replay`` inside."""
+        with self.tracer.span("eng.step", pool=self.name) as sp:
+            kind = self._step()
+            if sp is not None:
+                sp.kind = kind
+
+    def _step(self) -> str:
         if self._fault == "killed":
             raise EngineDead(f"engine fault injected: {self._fault}")
         if self._fault == "wedged":
             # a wedged step consumes wall time but never advances the
             # iteration clock — exactly the signature HealthPolicy keys
             # on (busy engine, frozen iteration counter)
-            return
+            return "idle"
         it0, done0 = self.iteration, self._completed_total
         self.iteration += 1
-        self._admit()
+        with self.tracer.span("eng.admit", pool=self.name):
+            self._admit()
         chunks: Dict[int, List[int]] = {}
         for s in range(self.n_max):
             req = self.slot_req[s]
@@ -737,47 +788,16 @@ class InferenceEngine:
             bool)
         occupied = sum(r is not None for r in self.slot_req)
         if self.paged:
-            for s, chunk in chunks.items():
-                ok = self._ensure_blocks(s,
-                                         int(self.slot_pos[s]) + len(chunk))
-                assert ok, "prefill outran its reservation (the prompt " \
-                    "is always fully covered, hint or not)"
-            if decode_mask.any():
-                # max tokens one decode-only dispatch can emit per row:
-                # decode_k micro-iterations x up to spec_k tokens each
-                # (the verify body clips each window to budget, so the
-                # per-slot advance never exceeds its admission-time
-                # worst-case reservation). Pre-provisioning here is
-                # what keeps the scan from ever re-entering the host
-                # allocator mid-dispatch.
-                k = self.decode_k * self.spec_k if not chunks else 1
-                breached = False
-                for s in np.where(decode_mask)[0]:
-                    req = self.slot_req[int(s)]
-                    if req is None:     # preempted by an earlier breach
-                        continue
-                    left = req.max_new_tokens - len(self.slot_out[int(s)])
-                    needed = int(self.slot_pos[s]) + min(k, left)
-                    if not self._ensure_blocks(int(s), needed):
-                        # tightened (l_out_hint) reservation outrun:
-                        # free blocks by preemption — possibly of this
-                        # very slot — and keep the dispatch going
-                        self._reservation_breach(int(s), needed,
-                                                 protected=chunks.keys())
-                        breached = True
-                if breached:
-                    # breach preemptions may have emptied slots the
-                    # mask was computed over (victims, or the breacher
-                    # itself) — only still-occupied rows decode; an
-                    # all-False mask falls through to the idle branch
-                    decode_mask &= np.array(
-                        [r is not None for r in self.slot_req], bool)
+            with self.tracer.span("eng.alloc", pool=self.name):
+                decode_mask = self._provision_blocks(chunks, decode_mask)
         if chunks and decode_mask.any():
             self._occ_slot_iters += occupied
             self._run_mixed(chunks, decode_mask)
+            kind = "mixed"
         elif chunks:
             self._occ_slot_iters += occupied
             self._run_prefill_chunks(chunks)
+            kind = "prefill"
         elif decode_mask.any():
             if self.spec_k > 1:
                 self._run_spec_scan(decode_mask)
@@ -786,10 +806,70 @@ class InferenceEngine:
             else:
                 self._occ_slot_iters += occupied
                 self._run_decode(decode_mask)
+            kind = "decode"
         else:
             self._occ_slot_iters += occupied
+            kind = "idle"
         self._update_rate_estimates(self.iteration - it0,
                                     self._completed_total - done0)
+        if self.paged and kind != "idle":
+            self._count_kv_blocks()
+        return kind
+
+    def _provision_blocks(self, chunks: Dict[int, List[int]],
+                          decode_mask: np.ndarray) -> np.ndarray:
+        """Allocate the blocks this step's dispatch will write: each
+        pending chunk, and up to one dispatch's tokens per decode row.
+        Returns the decode mask, narrowed when a reservation breach
+        preempted rows."""
+        for s, chunk in chunks.items():
+            ok = self._ensure_blocks(s,
+                                     int(self.slot_pos[s]) + len(chunk))
+            assert ok, "prefill outran its reservation (the prompt " \
+                "is always fully covered, hint or not)"
+        if decode_mask.any():
+            # max tokens one decode-only dispatch can emit per row:
+            # decode_k micro-iterations x up to spec_k tokens each
+            # (the verify body clips each window to budget, so the
+            # per-slot advance never exceeds its admission-time
+            # worst-case reservation). Pre-provisioning here is
+            # what keeps the scan from ever re-entering the host
+            # allocator mid-dispatch.
+            k = self.decode_k * self.spec_k if not chunks else 1
+            breached = False
+            for s in np.where(decode_mask)[0]:
+                req = self.slot_req[int(s)]
+                if req is None:     # preempted by an earlier breach
+                    continue
+                left = req.max_new_tokens - len(self.slot_out[int(s)])
+                needed = int(self.slot_pos[s]) + min(k, left)
+                if not self._ensure_blocks(int(s), needed):
+                    # tightened (l_out_hint) reservation outrun:
+                    # free blocks by preemption — possibly of this
+                    # very slot — and keep the dispatch going
+                    self._reservation_breach(int(s), needed,
+                                             protected=chunks.keys())
+                    breached = True
+            if breached:
+                # breach preemptions may have emptied slots the
+                # mask was computed over (victims, or the breacher
+                # itself) — only still-occupied rows decode; an
+                # all-False mask falls through to the idle branch
+                decode_mask &= np.array(
+                    [r is not None for r in self.slot_req], bool)
+        return decode_mask
+
+    def _count_kv_blocks(self) -> None:
+        """Add this step's paged KV to the block counters: blocks
+        reserved (referenced + worst case not yet allocated, the
+        allocator's own accounting) and blocks holding written tokens
+        (each occupied slot's ceil(position / block_size))."""
+        self.kv_reserved_block_steps += \
+            self.num_blocks - self._available_blocks() + self._reserved
+        bs = self.block_size
+        self.kv_used_block_steps += sum(
+            -(-int(self.slot_pos[s]) // bs) for s in range(self.n_max)
+            if self.slot_req[s] is not None)
 
     # ------------------------------------------------------------ internals
     def _worst_case_blocks(self, req: ServeRequest) -> int:
@@ -886,6 +966,7 @@ class InferenceEngine:
                     break
                 # The FIFO head DEFERS: the allocatable blocks cannot
                 # cover its worst case (DESIGN.md §Paged KV cache).
+                self.admissions_deferred += 1
                 # Escalations, in order:
                 # 1) preemption (opt-in): free blocks by preempting the
                 #    most recently admitted decoding slot. A RESUMED
@@ -1024,8 +1105,7 @@ class InferenceEngine:
             # fully cached prompt: decode can start this same iteration
             # from the last prompt token
             self.slot_last_tok[s] = req.tokens[-1]
-        self._queue_iters[req.rid] = self._queue_iters.get(req.rid, 0) \
-            + self.iteration - self._enqueued_at.pop(req.rid)
+        self._mark_admitted(req.rid)
         return "admitted"
 
     def _try_hol_bypass(self, s: int) -> bool:
@@ -1118,7 +1198,7 @@ class InferenceEngine:
         # enqueue BEFORE releasing so the idle-point invariant check
         # sees the preempted rid queued
         self.waiting.insert(min(requeue_index, len(self.waiting)), req)
-        self._enqueued_at[req.rid] = self.iteration
+        self._mark_queued(req.rid)
         self.slot_req[s] = None
         self.slot_out[s] = []
         self.slot_pos[s] = 0
@@ -1154,7 +1234,7 @@ class InferenceEngine:
         self._rid_preemptions[req.rid] = \
             self._rid_preemptions.get(req.rid, 0) + 1
         self.waiting.insert(min(requeue_index, len(self.waiting)), req)
-        self._enqueued_at[req.rid] = self.iteration
+        self._mark_queued(req.rid)
         self.slot_req[s] = None
         self.slot_out[s] = []
         self.slot_pos[s] = 0
@@ -1260,8 +1340,7 @@ class InferenceEngine:
         self.slot_prefill_left[s] = []
         self.slot_out[s] = list(state.out)
         self.slot_last_tok[s] = state.last_tok
-        self._queue_iters[req.rid] = self._queue_iters.get(req.rid, 0) \
-            + self.iteration - self._enqueued_at.pop(req.rid)
+        self._mark_admitted(req.rid)
         self.overload_stats["swapped_in"] += 1
         return "admitted"
 
@@ -1434,22 +1513,25 @@ class InferenceEngine:
         return tokens, lengths
 
     def _run_prefill_chunks(self, chunks: Dict[int, List[int]]) -> None:
-        tokens, lengths = self._bucket_chunks(chunks)
-        # snapshot slot_pos: jnp.asarray may alias host memory zero-copy
-        # and dispatch is async, so passing the live (mutated-below)
-        # array would race the device read
-        start = np.array(self.slot_pos, np.int32)
-        if self.paged:
-            self.cache = self._prefill_step(
-                self.params, self.cache, self._upload(tokens),
-                self._block_table_device(), self._upload(start),
-                self._upload(lengths))
-        else:
-            self.cache = self._prefill_step(
-                self.params, self.cache, self._upload(tokens),
-                self._upload(start), self._upload(lengths))
+        tr = self.tracer
+        with tr.span("eng.dispatch", pool=self.name):
+            tokens, lengths = self._bucket_chunks(chunks)
+            # snapshot slot_pos: jnp.asarray may alias host memory
+            # zero-copy and dispatch is async, so passing the live
+            # (mutated-below) array would race the device read
+            start = np.array(self.slot_pos, np.int32)
+            if self.paged:
+                self.cache = self._prefill_step(
+                    self.params, self.cache, self._upload(tokens),
+                    self._block_table_device(), self._upload(start),
+                    self._upload(lengths))
+            else:
+                self.cache = self._prefill_step(
+                    self.params, self.cache, self._upload(tokens),
+                    self._upload(start), self._upload(lengths))
         self.dispatches += 1
-        self._advance_prefill_host(chunks)
+        with tr.span("eng.replay", pool=self.name):
+            self._advance_prefill_host(chunks)
 
     def _advance_prefill_host(self, chunks: Dict[int, List[int]]) -> None:
         """Host bookkeeping for one dispatched chunk per slot (shared
@@ -1712,27 +1794,29 @@ class InferenceEngine:
                 or self.slot_pos[s] >= self.c_max)
 
     def _run_decode(self, mask: np.ndarray) -> None:
-        # snapshot host state (see _run_prefill_chunks: async dispatch
-        # must never observe the in-place updates below)
-        toks = self._upload(np.array(self.slot_last_tok[:, None]))
-        pos = self._upload(np.array(self.slot_pos))
-        if self.paged:
-            next_tok, self.cache = self._decode(self.params, self.cache,
-                                                toks,
-                                                self._block_table_device(),
-                                                pos, self._upload(mask))
-        else:
-            next_tok, self.cache = self._decode(self.params, self.cache,
-                                                toks, pos,
-                                                self._upload(mask))
+        tr = self.tracer
+        with tr.span("eng.dispatch", pool=self.name):
+            # snapshot host state (see _run_prefill_chunks: async
+            # dispatch must never observe the in-place updates below)
+            toks = self._upload(np.array(self.slot_last_tok[:, None]))
+            pos = self._upload(np.array(self.slot_pos))
+            if self.paged:
+                next_tok, self.cache = self._decode(
+                    self.params, self.cache, toks,
+                    self._block_table_device(), pos, self._upload(mask))
+            else:
+                next_tok, self.cache = self._decode(
+                    self.params, self.cache, toks, pos, self._upload(mask))
         self.dispatches += 1
         self.decode_dispatches += 1
         self._decode_only_tokens += int(mask.sum())
         self._dev_dirty = True
-        next_tok = np.asarray(next_tok)
-        for s in np.where(mask)[0]:
-            if self._append_token(int(s), int(next_tok[s])):
-                self._finish_slot(int(s))
+        with tr.span("eng.sync", pool=self.name):
+            next_tok = np.asarray(next_tok)
+        with tr.span("eng.replay", pool=self.name):
+            for s in np.where(mask)[0]:
+                if self._append_token(int(s), int(next_tok[s])):
+                    self._finish_slot(int(s))
 
     def _run_decode_scan(self, mask: np.ndarray) -> None:
         """One dispatch, ``decode_k`` decode iterations: the lax.scan
@@ -1741,37 +1825,41 @@ class InferenceEngine:
         The host replays the same completion rule over the batch to
         update its mirrors WITHOUT re-dirtying the device copy."""
         k = self.decode_k
-        tok, pos, active, budget = self._device_decode_state(mask)
-        if self.paged:
-            carry, emitted = self._decode_scan(
-                self.params, self.cache, tok, self._block_table_device(),
-                pos, active, budget)
-        else:
-            carry, emitted = self._decode_scan(
-                self.params, self.cache, tok, pos, active, budget)
+        tr = self.tracer
+        with tr.span("eng.dispatch", pool=self.name):
+            tok, pos, active, budget = self._device_decode_state(mask)
+            if self.paged:
+                carry, emitted = self._decode_scan(
+                    self.params, self.cache, tok,
+                    self._block_table_device(), pos, active, budget)
+            else:
+                carry, emitted = self._decode_scan(
+                    self.params, self.cache, tok, pos, active, budget)
         self.cache = carry[0]
         self._dev_state = carry[1:]
         self.dispatches += 1
         self.decode_dispatches += 1
-        emitted = np.asarray(emitted)          # the single host sync
+        with tr.span("eng.sync", pool=self.name):
+            emitted = np.asarray(emitted)      # the single host sync
         self.iteration += k - 1                # step() already added 1
-        for s in np.where(mask)[0]:
-            s = int(s)
-            done = False
-            for j in range(k):
-                t = int(emitted[s, j])
-                if t < 0:
-                    break
-                self._occ_slot_iters += 1
-                self._decode_only_tokens += 1
-                done = self._append_token(s, t)
+        with tr.span("eng.replay", pool=self.name):
+            for s in np.where(mask)[0]:
+                s = int(s)
+                done = False
+                for j in range(k):
+                    t = int(emitted[s, j])
+                    if t < 0:
+                        break
+                    self._occ_slot_iters += 1
+                    self._decode_only_tokens += 1
+                    done = self._append_token(s, t)
+                    if done:
+                        break
                 if done:
-                    break
-            if done:
-                self._finish_slot(s)
-            # a row that stayed live emitted every micro-iteration, so
-            # the per-token occupancy increments above already credit
-            # it with all k iterations
+                    self._finish_slot(s)
+                # a row that stayed live emitted every micro-iteration,
+                # so the per-token occupancy increments above already
+                # credit it with all k iterations
 
     def _run_spec_scan(self, mask: np.ndarray) -> None:
         """One dispatch, ``decode_k`` speculative verify iterations
@@ -1808,52 +1896,57 @@ class InferenceEngine:
                 drafts[s, :len(d)] = d
                 dlen[s] = len(d)
                 self.spec_stats["drafted_tokens"] += len(d)
-        tok, pos, active, budget = self._device_decode_state(mask)
-        d_dev = self._upload(drafts)
-        n_dev = self._upload(dlen)
-        if self.paged:
-            carry, (emitted, fed) = self._decode_scan(
-                self.params, self.cache, tok, self._block_table_device(),
-                pos, active, budget, d_dev, n_dev)
-        else:
-            carry, (emitted, fed) = self._decode_scan(
-                self.params, self.cache, tok, pos, active, budget,
-                d_dev, n_dev)
+        tr = self.tracer
+        with tr.span("eng.dispatch", pool=self.name):
+            tok, pos, active, budget = self._device_decode_state(mask)
+            d_dev = self._upload(drafts)
+            n_dev = self._upload(dlen)
+            if self.paged:
+                carry, (emitted, fed) = self._decode_scan(
+                    self.params, self.cache, tok,
+                    self._block_table_device(), pos, active, budget,
+                    d_dev, n_dev)
+            else:
+                carry, (emitted, fed) = self._decode_scan(
+                    self.params, self.cache, tok, pos, active, budget,
+                    d_dev, n_dev)
         self.cache = carry[0]
         # the carry's draft cursor is per-dispatch scratch; only the
         # (tok, pos, active, budget) slot state persists on device
         self._dev_state = carry[1:5]
         self.dispatches += 1
         self.decode_dispatches += 1
-        emitted = np.asarray(emitted)        # (K, n_max, W) — the sync
-        fed = np.asarray(fed)                # (K, n_max) drafts fed
+        with tr.span("eng.sync", pool=self.name):
+            emitted = np.asarray(emitted)    # (K, n_max, W) — the sync
+            fed = np.asarray(fed)            # (K, n_max) drafts fed
         self.iteration += k - 1              # step() already added 1
-        for s in np.where(mask)[0]:
-            s = int(s)
-            done = False
-            for m in range(k):
-                e = 0
-                for i in range(w_max):
-                    t = int(emitted[m, s, i])
-                    if t < 0:
-                        break
-                    e += 1
-                    self._decode_only_tokens += 1
-                    done = self._append_token(s, t)
+        with tr.span("eng.replay", pool=self.name):
+            for s in np.where(mask)[0]:
+                s = int(s)
+                done = False
+                for m in range(k):
+                    e = 0
+                    for i in range(w_max):
+                        t = int(emitted[m, s, i])
+                        if t < 0:
+                            break
+                        e += 1
+                        self._decode_only_tokens += 1
+                        done = self._append_token(s, t)
+                        if done:
+                            break
+                    if e:
+                        # one live verify window == one occupied model
+                        # iteration, however many tokens it accepted —
+                        # utilization stays comparable across kappa
+                        self._occ_slot_iters += 1
+                        self.spec_stats["proposed_tokens"] += int(fed[m, s])
+                        self.spec_stats["accepted_tokens"] += e - 1
+                        self.spec_stats["verify_windows"] += 1
                     if done:
                         break
-                if e:
-                    # one live verify window == one occupied model
-                    # iteration, however many tokens it accepted —
-                    # utilization stays comparable across kappa
-                    self._occ_slot_iters += 1
-                    self.spec_stats["proposed_tokens"] += int(fed[m, s])
-                    self.spec_stats["accepted_tokens"] += e - 1
-                    self.spec_stats["verify_windows"] += 1
                 if done:
-                    break
-            if done:
-                self._finish_slot(s)
+                    self._finish_slot(s)
 
     def spec_acceptance_rate(self) -> float:
         """Accepted / proposed draft tokens over all verify windows
@@ -1880,23 +1973,27 @@ class InferenceEngine:
         """Fused prefill+decode dispatch: ONE jitted call advances all
         pending chunks AND all decode rows (M.mixed_step) — the mixed
         iteration previously cost two host dispatches."""
-        tokens, lengths = self._bucket_chunks(chunks)
-        # snapshot host state (async-dispatch aliasing rule)
-        pos = self._upload(np.array(self.slot_pos, np.int32))
-        toks = self._upload(np.array(self.slot_last_tok[:, None]))
-        if self.paged:
-            next_tok, self.cache = self._mixed(
-                self.params, self.cache, self._upload(tokens),
-                self._block_table_device(), pos, self._upload(lengths),
-                toks, self._upload(mask))
-        else:
-            next_tok, self.cache = self._mixed(
-                self.params, self.cache, self._upload(tokens), pos,
-                self._upload(lengths), toks, self._upload(mask))
+        tr = self.tracer
+        with tr.span("eng.dispatch", pool=self.name):
+            tokens, lengths = self._bucket_chunks(chunks)
+            # snapshot host state (async-dispatch aliasing rule)
+            pos = self._upload(np.array(self.slot_pos, np.int32))
+            toks = self._upload(np.array(self.slot_last_tok[:, None]))
+            if self.paged:
+                next_tok, self.cache = self._mixed(
+                    self.params, self.cache, self._upload(tokens),
+                    self._block_table_device(), pos, self._upload(lengths),
+                    toks, self._upload(mask))
+            else:
+                next_tok, self.cache = self._mixed(
+                    self.params, self.cache, self._upload(tokens), pos,
+                    self._upload(lengths), toks, self._upload(mask))
         self.dispatches += 1
         self._dev_dirty = True
-        next_tok = np.asarray(next_tok)
-        self._advance_prefill_host(chunks)
-        for s in np.where(mask)[0]:
-            if self._append_token(int(s), int(next_tok[s])):
-                self._finish_slot(int(s))
+        with tr.span("eng.sync", pool=self.name):
+            next_tok = np.asarray(next_tok)
+        with tr.span("eng.replay", pool=self.name):
+            self._advance_prefill_host(chunks)
+            for s in np.where(mask)[0]:
+                if self._append_token(int(s), int(next_tok[s])):
+                    self._finish_slot(int(s))

@@ -87,6 +87,25 @@ def fleet_metrics(runtime) -> List[Metric]:
             "fleetopt_queue_wait_est_iters", "gauge",
             "Rolling queue-wait estimate (iterations) used by "
             "stability-aware admission"),
+        "queue_wait_s": Metric(
+            "fleetopt_queue_wait_seconds_sum", "counter",
+            "Wall-clock seconds requests waited from submit to admission "
+            "into a slot, summed"),
+        "queue_admissions": Metric(
+            "fleetopt_queue_wait_seconds_count", "counter",
+            "Admissions into a slot that the queue-wait sum covers"),
+        "deferred": Metric(
+            "fleetopt_admissions_deferred_total", "counter",
+            "Queue-head admissions deferred because the KV block pool "
+            "could not cover the request's worst case"),
+        "kv_reserved": Metric(
+            "fleetopt_kv_reserved_block_steps_total", "counter",
+            "KV blocks reserved (allocated + worst case not yet "
+            "allocated), summed over dispatching steps"),
+        "kv_used": Metric(
+            "fleetopt_kv_used_block_steps_total", "counter",
+            "KV blocks holding written tokens, summed over dispatching "
+            "steps"),
         "slots": Metric(
             "fleetopt_slots", "gauge", "Provisioned engine slots"),
         "iterations": Metric(
@@ -133,6 +152,13 @@ def fleet_metrics(runtime) -> List[Metric]:
         per_pool["queue_depth"].add(snap["queue_depth"], pool=name)
         per_pool["queue_wait"].add(snap["queue_wait_est_iters"],
                                    pool=name)
+        per_pool["queue_wait_s"].add(eng.queue_wait_ns / 1e9, pool=name)
+        per_pool["queue_admissions"].add(eng.queue_admissions, pool=name)
+        per_pool["deferred"].add(eng.admissions_deferred, pool=name)
+        if eng.paged:
+            per_pool["kv_reserved"].add(eng.kv_reserved_block_steps,
+                                        pool=name)
+            per_pool["kv_used"].add(eng.kv_used_block_steps, pool=name)
         per_pool["slots"].add(eng.n_max, pool=name)
         per_pool["iterations"].add(eng.iteration, pool=name)
         per_pool["host_tier"].add(snap["host_tier_blocks"], pool=name)

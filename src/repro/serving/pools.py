@@ -28,6 +28,7 @@ from repro.core.workload import OutputLenPredictor, Request, get_workload
 from repro.serving.config import ServingConfig
 from repro.serving.engine import InferenceEngine, ServeRequest, ServeResult
 from repro.serving.tokenizer import ByteChunkTokenizer
+from repro.serving.tracing import Tracer
 
 
 @dataclasses.dataclass
@@ -130,6 +131,9 @@ class FleetRuntime:
             lout_predictor=(self.lout_predictor
                             if scfg.lout_routing else None))
         names = pool_names(k)
+        # spans of the whole fleet: this runtime's routing and C&R, its
+        # engines and the gateway over it (off until started)
+        self.tracer = Tracer()
         # The whole serving feature surface (paged / prefix_cache /
         # decode_k / spec_k / overload survival / lout reservation) is
         # configured per-engine by ONE ServingConfig; see its docstring
@@ -139,7 +143,8 @@ class FleetRuntime:
             names[i]: InferenceEngine(
                 cfg, params, n_maxes[i], c_maxes[i],
                 config=scfg.replace(mesh=self._submeshes[i],
-                                    tp_degree=1))
+                                    tp_degree=1),
+                name=names[i], tracer=self.tracer)
             for i in range(k)}
         # pristine host params, kept for live re-provisioning: engine
         # rebuilds re-shard from these instead of re-gathering a dead
@@ -227,8 +232,18 @@ class FleetRuntime:
                     l_in=prompt_tokens, l_out=req.max_output_tokens,
                     category=req.category,
                     prompt_bytes=len(req.text.encode("utf-8")))
-        decision = self.router.route(r, prompt_text=req.text,
-                                     session=req.session)
+        tr, st = self.tracer, self.router.stats
+        ms0 = st.compression_ms_sum
+        with tr.span("router.route", rid=req.rid) as sp:
+            decision = self.router.route(r, prompt_text=req.text,
+                                         session=req.session)
+            if sp is not None and st.compression_ms_sum > ms0:
+                # C&R ran inside route(): its span is the time that fed
+                # compression_ms_sum (one clock), ending about now
+                t1 = time.perf_counter_ns()
+                tr.interval("router.compress",
+                            t1 - int((st.compression_ms_sum - ms0) * 1e6),
+                            t1, rid=req.rid)
         if decision.pool in self.pool_down_until:
             left = self.pool_down_until[decision.pool] - time.monotonic()
             if left > 0:

@@ -133,7 +133,7 @@ def _move_request(src: InferenceEngine, dst: InferenceEngine,
     if state is not None:
         dst._preempted[rid] = _adopt_state(state, src, dst)
     dst.waiting.append(req)
-    dst._enqueued_at[rid] = dst.iteration
+    dst._mark_queued(rid)
     src._enqueued_at.pop(rid, None)
     for attr in ("_queue_iters", "_prefill_iters", "_rid_preemptions"):
         v = getattr(src, attr).pop(rid, None)
@@ -197,7 +197,7 @@ def reprovision(runtime, pool: str, *, n_max: Optional[int] = None,
     # requeued in slot order ahead of already-waiting arrivals
     checkpointed = old.drain_checkpoint()
     new_eng = InferenceEngine(runtime.cfg, runtime.params, new_n, new_c,
-                              config=ecfg)
+                              config=ecfg, name=pool, tracer=old.tracer)
     up = runtime.engines[names[i + 1]] if i + 1 < len(names) else None
     migrated = rerouted = 0
     for req in list(old.waiting):
@@ -284,7 +284,8 @@ def recover_pool(runtime, pool: str, *,
     old = runtime.engines[pool]
     salvaged = salvage_states(old)
     new_eng = InferenceEngine(runtime.cfg, runtime.params, old.n_max,
-                              old.c_max, config=old.config)
+                              old.c_max, config=old.config, name=pool,
+                              tracer=old.tracer)
     up_name = names[i + 1] if i + 1 < len(names) else pool
     migrated = 0
     for req, state in salvaged:

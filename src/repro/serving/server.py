@@ -165,27 +165,39 @@ class ServingGateway:
     async def _drive(self) -> None:
         """The ONLY place engines step while the gateway runs. Each
         pass: step every busy engine (executor — jitted dispatches
-        block), then flush whatever tokens those dispatches synced."""
+        block), then flush whatever tokens those dispatches synced.
+        With the runtime's tracer on, a pass is the span ``gw.pass``,
+        holding ``gw.lock_wait``, ``gw.hop`` (onto the executor thread)
+        and each ``eng.step``, ``gw.flush`` and ``gw.yield``."""
         loop = asyncio.get_running_loop()
+        tr = self.runtime.tracer
         while self._running:
-            async with self._lock:
-                busy = [n for n, e in self.runtime.engines.items()
-                        if e.busy()]
-                for name in busy:
-                    eng = self.runtime.engines[name]
-                    try:
-                        await loop.run_in_executor(None, eng.step)
-                    except EngineDead:
+            with tr.span("gw.pass"):
+                with tr.span("gw.lock_wait"):
+                    await self._lock.acquire()
+                try:
+                    busy = [n for n, e in self.runtime.engines.items()
+                            if e.busy()]
+                    for name in busy:
+                        eng = self.runtime.engines[name]
+                        try:
+                            await loop.run_in_executor(
+                                None, tr.handoff("gw.hop", eng.step))
+                        except EngineDead:
+                            self._recover(name)
+                    # wedged engines don't raise — their iteration clock
+                    # just stops advancing while busy; the health policy
+                    # spots the stall and the recovery path is identical
+                    for name in self.health.check(self.runtime):
                         self._recover(name)
-                # wedged engines don't raise — their iteration clock
-                # just stops advancing while busy; the health policy
-                # spots the stall and the recovery path is identical
-                for name in self.health.check(self.runtime):
-                    self._recover(name)
-                if self._pending:
-                    self._flush()
-            # yield to handlers; sleep longer when idle
-            await asyncio.sleep(0 if busy else self.idle_sleep_s)
+                    if self._pending:
+                        with tr.span("gw.flush"):
+                            self._flush()
+                finally:
+                    self._lock.release()
+                # yield to handlers; sleep longer when idle
+                with tr.span("gw.yield"):
+                    await asyncio.sleep(0 if busy else self.idle_sleep_s)
 
     def _recover(self, name: str) -> None:
         """Crash recovery under the gateway lock: salvage the dead
@@ -395,10 +407,14 @@ class ServingGateway:
     async def _completions(self, body: bytes, writer) -> int:
         p = self._parse_completion(body)
         rid = next(self._rid)
-        st = _Stream(queue=asyncio.Queue(), pool="", l_in_effective=0,
-                     prompt_tokens=self.runtime.tokenizer.count(
-                         p["prompt"]))
-        async with self._lock:
+        tr = self.runtime.tracer
+        # gw.handle: parsed body -> submitted to a pool's queue
+        with tr.span("gw.handle", rid=rid):
+            st = _Stream(queue=asyncio.Queue(), pool="", l_in_effective=0,
+                         prompt_tokens=self.runtime.tokenizer.count(
+                             p["prompt"]))
+            with tr.span("gw.lock_wait", rid=rid):
+                await self._lock.acquire()
             try:
                 decision = self.runtime.submit(GatewayRequest(
                     rid=rid, text=p["prompt"],
@@ -409,11 +425,14 @@ class ServingGateway:
                     503, f"{e} (pool rebuilding after a fault)",
                     etype="overloaded_error",
                     retry_after=e.retry_after) from None
-            st.pool = decision.pool
-            st.l_in_effective = decision.l_in_effective
-            self._pending[rid] = st
-            if self.replanner is not None:
-                self.replanner.note_arrival()
+            else:
+                st.pool = decision.pool
+                st.l_in_effective = decision.l_in_effective
+                self._pending[rid] = st
+                if self.replanner is not None:
+                    self.replanner.note_arrival()
+            finally:
+                self._lock.release()
         if p["stream"]:
             return await self._stream_response(rid, st, decision, writer)
         return await self._batch_response(rid, st, decision, writer)
@@ -452,11 +471,14 @@ class ServingGateway:
                      b"Cache-Control: no-cache\r\n"
                      b"Connection: close\r\n\r\n")
         await writer.drain()
+        tr = self.runtime.tracer
         while True:
             kind, payload = await self._next_event(rid, st)
             if kind == "tokens":
-                self._write_sse(writer, self._chunk(rid, payload, None))
-                await writer.drain()
+                with tr.span("gw.write", rid=rid):
+                    self._write_sse(writer, self._chunk(rid, payload,
+                                                        None))
+                    await writer.drain()
                 continue
             res = payload
             final = self._chunk(rid, [], self._finish_reason(res))
