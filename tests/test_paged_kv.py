@@ -6,11 +6,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from conftest import reduced_f32
 from repro.core.profiles import A100_LLAMA70B, TPU_V5E_LLAMA70B
 from repro.core.workload import get_workload
-from repro.kernels import ops, ref
+from repro.kernels import ops, paged_decode, ref
 from repro.models import layers as L
 from repro.models import model as M
 from repro.serving.engine import InferenceEngine, ServeRequest
@@ -36,20 +37,50 @@ PAGED_SHAPES = [  # (b, h, hkv, hd, block_s, nb, num_blocks)
     (3, 16, 2, 64, 64, 4, 16),
     (2, 2, 1, 64, 16, 16, 64),   # single kv head, deep table
 ]
+# (shape, seq_lens): each row's entries past its live pages point outside
+# the pool, so the kernel passes only if it never reads them. Pages per
+# compute block (paged_decode.pages_per_block): 8 heads x 128 at 16-token
+# pages put 32 (f32) or 64 (bf16) pages in a block, so the first shape is
+# one 40-page block in bf16 and 32 + 8 in f32, and the second is 64 + 8
+# in bf16; the third has 128-token pages. The lengths take 1, block_s,
+# block_s + 1, a block edge and nb * block_s.
+PAGED_EDGE_CASES = [
+    ((5, 48, 8, 128, 16, 40, 200), (1, 16, 17, 513, 640)),
+    ((4, 16, 8, 128, 16, 72, 288), (1, 17, 1025, 1152)),
+    ((4, 8, 2, 64, 128, 4, 16), (1, 128, 129, 512)),
+]
 
 
-@pytest.mark.parametrize("b,h,hkv,hd,bs,nb,p", PAGED_SHAPES)
+def _outside_past_live(bt, seq, bs, num_blocks):
+    """Point every block-table entry past a row's live pages outside the
+    pool (each row its own out-of-range value)."""
+    bt = np.array(bt)
+    for i, n in enumerate(seq):
+        bt[i, -(-n // bs):] = num_blocks + 1000 * (i + 1)
+    return jnp.asarray(bt, jnp.int32)
+
+
+@pytest.mark.parametrize(
+    "b,h,hkv,hd,bs,nb,p,seq",
+    [s + (None,) for s in PAGED_SHAPES] + [s + (q,) for s, q in
+                                             PAGED_EDGE_CASES])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_gqa_decode_allclose(b, h, hkv, hd, bs, nb, p, dtype):
+def test_paged_gqa_decode_allclose(b, h, hkv, hd, bs, nb, p, seq, dtype):
     key = jax.random.PRNGKey(b * 100 + h)
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (b, h, hd), dtype)
     kp = jax.random.normal(ks[1], (p, bs, hkv, hd), dtype)
     vp = jax.random.normal(ks[2], (p, bs, hkv, hd), dtype)
     bt = _shuffled_tables(np.random.default_rng(b), b, nb, p)
-    seq = jax.random.randint(ks[3], (b,), 1, nb * bs + 1)
+    if seq is None:
+        seq = jax.random.randint(ks[3], (b,), 1, nb * bs + 1)
+        bt_ref = bt
+    else:
+        bt = _outside_past_live(bt, seq, bs, p)
+        bt_ref = jnp.clip(bt, 0, p - 1)   # what the oracle gathers is masked
+        seq = jnp.asarray(seq, jnp.int32)
     out = ops.paged_gqa_decode(q, kp, vp, bt, seq)
-    want = ref.paged_gqa_decode_ref(q, kp, vp, bt, seq)
+    want = ref.paged_gqa_decode_ref(q, kp, vp, bt_ref, seq)
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32),
@@ -82,7 +113,15 @@ def test_paged_kernel_matches_contiguous_kernel():
                                atol=2e-5)
 
 
-def test_paged_kernel_inactive_rows_zero():
+@pytest.mark.parametrize("active,outside", [
+    ((True, False, True), False),
+    ((False, False, False), False),
+    # inactive rows' tables point wholly outside the pool, active rows'
+    # past their live pages: an inactive row copies nothing
+    ((True, False, True), True),
+    ((False, False, False), True),
+])
+def test_paged_kernel_inactive_rows_zero(active, outside):
     key = jax.random.PRNGKey(5)
     ks = jax.random.split(key, 3)
     b, h, hkv, hd, bs, nb, p = 3, 8, 2, 64, 16, 4, 16
@@ -91,12 +130,57 @@ def test_paged_kernel_inactive_rows_zero():
     vp = jax.random.normal(ks[2], (p, bs, hkv, hd))
     bt = _shuffled_tables(np.random.default_rng(1), b, nb, p)
     seq = jnp.asarray([5, 40, 60], jnp.int32)
-    active = jnp.asarray([True, False, True])
-    out = np.asarray(ops.paged_gqa_decode(q, kp, vp, bt, seq, active))
+    active = np.asarray(active)
+    bt_kernel = bt
+    if outside:
+        bt_kernel = _outside_past_live(
+            bt, [int(n) if a else 0 for n, a in zip(seq, active)], bs, p)
+    out = np.asarray(ops.paged_gqa_decode(q, kp, vp, bt_kernel, seq,
+                                          jnp.asarray(active)))
     want = np.asarray(ref.paged_gqa_decode_ref(q, kp, vp, bt, seq))
-    np.testing.assert_allclose(out[0], want[0], atol=2e-5)
-    np.testing.assert_allclose(out[2], want[2], atol=2e-5)
-    assert np.all(out[1] == 0.0)
+    np.testing.assert_allclose(out[active], want[active], atol=2e-5)
+    assert np.all(out[~active] == 0.0)
+
+
+def _heads_by_value(block, hkv, tokens):
+    """``paged_decode._heads`` on a loaded block: Pallas's TPU interpreter
+    reads no reshaped ref, so the copy test picks each head out of the
+    block's value instead of by a strided load."""
+    x = block[...].astype(jnp.float32).reshape(tokens, hkv, block.shape[-1])
+    for g in range(hkv):
+        yield x[:, g]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_copies_live_pages_only(monkeypatch, dtype):
+    """The kernel's page copies under Pallas's TPU interpreter, where a
+    copy lands only when it is waited on, unset VMEM reads NaN and a
+    read out of range raises: several blocks a row, a copy running
+    ahead into the next live row past inactive and empty ones, and
+    block-table entries outside the pool past every live page."""
+    monkeypatch.setattr(paged_decode, "_heads", _heads_by_value)
+    b, h, hkv, hd, bs, nb, p = 6, 16, 8, 128, 16, 72, 432
+    seq = np.asarray([1, 0, 1025, 17, 640, 1152])
+    active = np.asarray([True, True, True, False, True, True])
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    q = jax.random.normal(ks[0], (b, h, hd), dtype)
+    kp = jax.random.normal(ks[1], (p, bs, hkv, hd), dtype)
+    vp = jax.random.normal(ks[2], (p, bs, hkv, hd), dtype)
+    bt = _shuffled_tables(np.random.default_rng(2), b, nb, p)
+    bt = _outside_past_live(bt, np.where(active, seq, 0), bs, p)
+    params = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan",
+                                   out_of_bounds_reads="raise")
+    out = np.asarray(paged_decode.paged_gqa_decode(
+        q, kp, vp, bt, jnp.asarray(seq, jnp.int32), jnp.asarray(active),
+        interpret=params), np.float32)
+    want = np.asarray(ref.paged_gqa_decode_ref(
+        q, kp, vp, jnp.clip(bt, 0, p - 1), jnp.asarray(seq, jnp.int32)),
+        np.float32)
+    live = active & (seq > 0)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(out[live], want[live], atol=tol, rtol=tol)
+    assert np.all(out[~live] == 0.0)
 
 
 # ----------------------------------------------------------- paged writes

@@ -1,12 +1,15 @@
 """The decode kernels compile for a TPU v5e at Minitron-8B widths.
 
 Nothing runs: the TPU compiler installed with jax compiles for a chip
-that is described, not attached (8 KV heads, 4 query heads per KV
+that is described, not attached (8 KV heads, 4 or 6 query heads per KV
 head, head_dim 128, bf16), which catches what interpret mode cannot —
 block shapes off the (8, 128) tiling, too much VMEM, 1-D blocks. The
 topology is described inside a fixture, never at import, so that only
 the test worker that runs this file loads the TPU library.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -57,19 +60,46 @@ def test_gqa_decode_compiles_for_v5e(one_chip, s_max):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _pool_copies(hlo: str, pool_shape) -> list:
+    """Instructions other than the pool's parameters and bitcasts whose
+    result holds as many elements as the page pool: a transpose or copy
+    of the pool in another layout."""
+    size = math.prod(pool_shape)
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (\S+?)\(", line)
+        if m and m.group(2) not in ("parameter", "bitcast") and math.prod(
+                int(d) for d in m.group(1).split(",") if d) == size:
+            found.append(line.strip()[:160])
+    return found
+
+
+def _compile_paged(sharding, b, h, pages, block_s, nb):
+    args = (_spec(sharding, (b, h, HD), jnp.bfloat16),
+            _spec(sharding, (pages, block_s, HKV, HD), jnp.bfloat16),
+            _spec(sharding, (pages, block_s, HKV, HD), jnp.bfloat16),
+            _spec(sharding, (b, nb), jnp.int32),
+            _spec(sharding, (b,), jnp.int32),
+            _spec(sharding, (b,), jnp.bool_))
+    hlo = jax.jit(
+        lambda q, k, v, bt, sl, act: paged_gqa_decode(q, k, v, bt, sl, act,
+                                                      interpret=False)
+    ).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert not _pool_copies(hlo, (pages, block_s, HKV, HD))
+
+
 @pytest.mark.parametrize("block_s", [16, 128])
 def test_paged_gqa_decode_compiles_for_v5e(one_chip, block_s):
     """Paged flash decode over a 32k-token pool, at the engine's
     default 16-token page and at a 128-token page."""
-    pages, nb = 32768 // block_s, 2048 // block_s
-    args = (_spec(one_chip, (B, H, HD), jnp.bfloat16),
-            _spec(one_chip, (pages, block_s, HKV, HD), jnp.bfloat16),
-            _spec(one_chip, (pages, block_s, HKV, HD), jnp.bfloat16),
-            _spec(one_chip, (B, nb), jnp.int32),
-            _spec(one_chip, (B,), jnp.int32),
-            _spec(one_chip, (B,), jnp.bool_))
-    compiled = jax.jit(
-        lambda q, k, v, bt, sl, act: paged_gqa_decode(q, k, v, bt, sl, act,
-                                                      interpret=False)
-    ).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    _compile_paged(one_chip, B, H, 32768 // block_s, block_s, 2048 // block_s)
+
+
+@pytest.mark.parametrize("b,nb", [(16, 256), (2, 1024)])
+def test_paged_gqa_decode_compiles_at_benchmark_shapes(one_chip, b, nb):
+    """The benchmark's pools: 16 short slots of 256 pages and 2 long
+    slots of 1,024, 16-token pages in a 2,048-page pool, 48/8 heads x
+    128. The kernel reads the token-major pool where it lies: no copy of
+    it in another layout is left in the module."""
+    _compile_paged(one_chip, b, 48, 2048, 16, nb)
