@@ -3,7 +3,9 @@ it, drive it with open-loop HTTP traffic, measure a window, and check
 what the window served against the plain reference.
 
 Everything that belongs to one configuration, traffic mix or per-layer
-metric is a file found by its name: ``configs/<config>.json``,
+metric is a file found by its name: ``configs/<config>.json``, the
+model module that configuration names, ``models/<model>.py`` (weights,
+reference, program config, counts and kernel names: ``MODEL_API``),
 ``traffic/<mix>.json``, ``metrics/<metric>.py`` (a ``read(record)``
 that returns a number, or None where it finds nothing to read).
 """
@@ -19,7 +21,7 @@ import os
 import tempfile
 import time
 from pathlib import Path
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
@@ -31,18 +33,20 @@ from chipbench import reference as R  # noqa: E402
 from chipbench import stats as S  # noqa: E402
 from chipbench import trace as TR  # noqa: E402
 from chipbench import traffic as T  # noqa: E402
-from chipbench import weights as W  # noqa: E402
 
 # The traced part of a --trace 1 window: it starts this share of the
 # window in and lasts TRACE_S seconds (or this share of a short window).
 TRACE_START, TRACE_S, TRACE_SHARE = 0.3, 4.0, 0.4
-# The paged decode kernel, by the name the device trace gives it
-# (its ops read "%paged_gqa_decode.<n> = ...").
-KERNELS = {"paged_decode": "paged_gqa_decode"}
-# Reference check: at most this many requests, drawn until they cover
-# the configuration's ``min_compared_tokens`` (short-output traffic
-# such as azure, ~46 tokens a request, needs a dozen or more).
-CHECK_REQUESTS = 32
+# Reference check: requests are drawn until they cover the
+# configuration's ``min_compared_tokens``, at most this many. Azure
+# traffic averages ~46 served tokens a request, but 58 of its 96 ask for
+# 8, so 32 draws could fall short of 600; 64 reach it.
+CHECK_REQUESTS = 64
+# Where a configuration's "model" is found, and what its module gives.
+MODELS = HERE / "models"
+MODEL_API = ("KERNELS", "program_config", "program_params", "layer_params",
+             "top_params", "Forward", "prefill_flops", "decode_flops",
+             "decode_kernel_cost")
 
 
 # ------------------------------------------------------------ discovery
@@ -63,6 +67,27 @@ def load_config(name: str) -> dict:
     if not path.is_file():
         raise FileNotFoundError(f"no configuration {name!r} at {path}")
     return json.loads(path.read_text())
+
+
+def load_model(cfgj: dict):
+    """The model module that a configuration names by its "model" key:
+    ``MODELS/<model>.py``, with every name of ``MODEL_API``."""
+    if "model" not in cfgj:
+        raise KeyError(f"configuration {cfgj.get('name')!r} has no "
+                       f"\"model\" key naming its module in {MODELS}")
+    path = MODELS / f"{cfgj['model']}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model module {cfgj['model']!r} at "
+                                f"{path}")
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_model_" + cfgj["model"], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [n for n in MODEL_API if not hasattr(mod, n)]
+    if missing:
+        raise AttributeError(f"model module {path} lacks "
+                             f"{', '.join(missing)}")
+    return mod
 
 
 def load_reader(name: str):
@@ -92,9 +117,11 @@ def metrics_for(bench: dict, cell: str, section: str) -> List[dict]:
 # ------------------------------------------------------------ recording
 @dataclasses.dataclass
 class Record:
-    """What one run leaves for the per-layer readers."""
+    """What one run leaves for the per-layer readers. ``model`` is the
+    configuration's model module (``load_model``), loaded once a run."""
     cfg: dict
     peak: dict
+    model: Any = None
     window_s: float = 0.0
     counters: Dict[str, dict] = dataclasses.field(default_factory=dict)
     spans: List[dict] = dataclasses.field(default_factory=list)
@@ -227,25 +254,10 @@ class CompileCounter:
 
 
 # --------------------------------------------------------------- build
-def program_config(cfgj: dict):
-    """The program's ModelConfig for a configuration file."""
-    from repro.configs.base import get_config
-    base = get_config(cfgj["arch"])
-    act = {"relu2": "squared_relu"}[cfgj["hidden_act"]]
-    return dataclasses.replace(
-        base, num_layers=cfgj["num_hidden_layers"],
-        d_model=cfgj["hidden_size"], num_heads=cfgj["num_attention_heads"],
-        num_kv_heads=cfgj["num_key_value_heads"],
-        head_dim=cfgj["head_dim"], d_ff=cfgj["intermediate_size"],
-        vocab_size=cfgj["vocab_size"], activation=act,
-        rope_theta=cfgj["rope_theta"], norm_eps=cfgj["norm_eps"],
-        tie_embeddings=cfgj["tie_word_embeddings"], dtype=cfgj["dtype"])
-
-
-def build(cfgj: dict, mix: dict, seed: int):
+def build(cfgj: dict, mix: dict, seed: int, model):
     """Plan the fleet as ``repro.launch.serve.build_fleet_runtime``
-    does (k=2 pools), with the benchmark's weights and each pool's KV
-    held at ``num_blocks``."""
+    does (k=2 pools), with the weights of the configuration's model
+    module ``model`` and each pool's KV held at ``num_blocks``."""
     import jax
     from repro.core.planner import plan_k_pool
     from repro.core.workload import get_workload
@@ -259,13 +271,13 @@ def build(cfgj: dict, mix: dict, seed: int):
         raise ValueError(f"mix scale {mix['scale']} != top context "
                          f"{sv['top_context']} / plan top "
                          f"{plan.pools[-1].c_max}")
-    params = W.program_params(cfgj, seed)
+    params = model.program_params(cfgj, seed)
     jax.block_until_ready(params)
     scfg = ServingConfig(paged=True, decode_k=sv["decode_k"],
                          decode_impl=sv["decode_impl"],
                          block_size=sv["block_size"],
                          num_blocks=sv.get("num_blocks"))
-    rt = FleetRuntime.from_plan(program_config(cfgj), params, plan,
+    rt = FleetRuntime.from_plan(model.program_config(cfgj), params, plan,
                                 slots_per_pool=sv["slots"],
                                 c_chunk=sv["c_chunk"], ctx_scale=scale,
                                 config=scfg)
@@ -419,8 +431,9 @@ def per_layer(rec: Record, names: List[str]) -> Dict[str, float]:
 # -------------------------------------------------------------- check
 def check(cfgj: dict, seed: int, out: dict, rec: Record, rt_shape: dict,
           control: bool = False) -> dict:
-    """Compare what the window served with the plain reference. Returns
-    {name: (value, limit)} plus details."""
+    """Compare what the window served with the plain reference, the
+    ``Forward`` of ``rec.model``. Returns {name: (value, limit)} plus
+    details."""
     limits = cfgj["check"]
     vocab = cfgj["vocab_size"]
     b, gamma = rt_shape["boundary"], rt_shape["gamma"]
@@ -458,8 +471,8 @@ def check(cfgj: dict, seed: int, out: dict, rec: Record, rt_shape: dict,
         done[k] = cand[k]
     pick = R.sample_requests(done, seed, limits["min_compared_tokens"],
                              CHECK_REQUESTS)
-    ref = R.Forward(cfgj, seed)
-    ctl = R.Forward(cfgj, seed, int8=True) if control else None
+    ref = rec.model.Forward(cfgj, seed)
+    ctl = rec.model.Forward(cfgj, seed, int8=True) if control else None
     gap_sum, ctl_sum, compared = 0.0, 0.0, 0
     per_req = []
     for rid in pick:
@@ -526,9 +539,10 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         {"bf16_flops_per_s": float("nan"), "hbm_bytes_per_s": float("nan")}
     compiles = CompileCounter()
     reqs = T.schedule(mix, seed, seconds)
-    rt, plan = build(cfgj, mix, seed)
+    model = load_model(cfgj)
+    rt, plan = build(cfgj, mix, seed, model)
     warm(rt)
-    rec = Record(cfg=cfgj, peak=peak)
+    rec = Record(cfg=cfgj, peak=peak, model=model)
     boundary = rt.router.boundaries[0]
     gamma = rt.router.gammas[0]
     log(f"fleet: boundary {boundary} gamma {gamma} pools "
@@ -545,7 +559,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool,
         setup_s = out["t0"] - t_start
         rec.window_s = seconds
         if trace:
-            rec.trace = TR.reduce(TR.load(tdir).planes, KERNELS)
+            rec.trace = TR.reduce(TR.load(tdir).planes, model.KERNELS)
             if os.environ.get("CHIPBENCH_DESCRIBE_TRACE"):
                 for line in TR.describe(TR.load(tdir).planes):
                     log("trace: " + line)

@@ -51,7 +51,6 @@ def main(argv=None) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from chipbench import harness as H
-    from chipbench import weights as W
     from repro.models import model as M
     from repro.serving.config import ServingConfig
     from repro.serving.engine import InferenceEngine
@@ -60,6 +59,7 @@ def main(argv=None) -> None:
     jax.config.update("jax_enable_compilation_cache", False)
     cfgj = H.load_config(args.config)
     cfgj["num_hidden_layers"] = args.layers
+    model = H.load_model(cfgj)
     sv = cfgj["serving"]
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
@@ -71,11 +71,12 @@ def main(argv=None) -> None:
     def abstract(tree):
         return jax.tree.map(lambda a: spec(a.shape, a.dtype), tree)
 
-    params = abstract(jax.eval_shape(lambda: W.program_params(cfgj, 0)))
+    params = abstract(jax.eval_shape(
+        lambda: model.program_params(cfgj, 0)))
     init_paged_cache = M.init_paged_cache
     M.init_paged_cache = lambda *a, **k: abstract(
         jax.eval_shape(lambda: init_paged_cache(*a, **k)))
-    cfg = H.program_config(cfgj)
+    cfg = model.program_config(cfgj)
     weights = _bytes(params)
     print(f"{args.config}, {args.layers} layers: weights "
           f"{weights / GiB:.3f} GiB")

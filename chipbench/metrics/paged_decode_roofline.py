@@ -1,10 +1,10 @@
 """Kernels: percent of its roofline that the paged decode kernel
 reached in the traced seconds. The least time is, per live row and
 decode step, the larger of its bytes over HBM bandwidth and its FLOPs
-over the bf16 peak, for the row's LIVE context (position + 1 keys)
-and every layer (``flops.decode_kernel_cost``); summed over the traced
-steps and divided by the kernel's summed device time in the trace.
-None without a trace or without the kernel in it."""
+over the bf16 peak, as the configuration's model module counts them
+for the row's live context (``rec.model.decode_kernel_cost``); summed
+over the traced steps and divided by the kernel's summed device time
+in the trace. None without a trace or without the kernel in it."""
 from chipbench import flops as F
 
 
@@ -21,7 +21,7 @@ def read(rec):
                 or s["t1"] > rec.trace_t1:
             continue
         for pos in s["decode"]:
-            f, b = F.decode_kernel_cost(rec.cfg, pos + 1)
+            f, b = rec.model.decode_kernel_cost(rec.cfg, pos)
             least += F.roofline_seconds(f, b, rec.peak)
     if least <= 0:
         return None

@@ -14,15 +14,18 @@
   sentences of the original in their order, the first three and last
   two kept, at most B - max_tokens tokens, and served as exactly the
   tokens of that text.
-* ``Forward``: the dense squared-ReLU GQA decoder in float32 at
-  ``highest`` matmul precision, layer by layer and in blocks of query
-  rows: RMSNorm with a scale, rotary embedding on each head's two
-  halves, causal attention with 8 KV heads shared by groups of query
-  heads, an ungated squared-ReLU MLP, final RMSNorm and an untied head.
-  Weights are regenerated from the seed (``weights.py``). With
-  ``int8=True`` every projection, the MLP and the head take int8
-  inputs (per-column weight scales, per-row activation scales) with
-  int32 accumulation: the control.
+* The blocks a model module's ``Forward`` (``models/<name>.py``) is
+  built from, in float32 at ``highest`` matmul precision: ``rms``
+  (RMSNorm with a scale), ``rope`` (rotary embedding on each head's two
+  halves), ``mm`` (a projection; with ``int8=True`` int8 inputs,
+  per-column weight scales and per-row activation scales, int32
+  accumulation: the control), ``gqa_attention`` (projections, rotary
+  embedding and causal attention with KV heads shared by groups of
+  query heads, in blocks of query rows, optionally over a window of
+  keys), ``head_logits`` (final RMSNorm and an untied head in blocks of
+  the vocabulary), ``forward_logits`` (the sequence padded to a bucket,
+  the layers one by one, the head on the compared rows only) and
+  ``LayerwiseForward``, which composes them around a module's layer.
 
 The engine feeds the last prompt token once more at position n before
 its first output, so a request of prompt p (n tokens) that served
@@ -33,13 +36,13 @@ from __future__ import annotations
 
 import math
 import re
-from typing import Dict, List, Sequence, Set
+from typing import Callable, Dict, List, Optional, Sequence, Set
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench.weights import draw, layer, root_key, _leaf_key
+from chipbench import weights as W
 
 BYTES_PER_TOKEN = 4
 COMPRESSIBLE = ("prose", "rag")
@@ -128,7 +131,7 @@ def _q8(x, axis):
     return jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8), scale
 
 
-def _mm(x, w, int8: bool):
+def mm(x, w, int8: bool):
     if not int8:
         return jnp.dot(x, w, precision=jax.lax.Precision.HIGHEST)
     xq, sx = _q8(x, axis=-1)
@@ -137,11 +140,11 @@ def _mm(x, w, int8: bool):
     return acc.astype(jnp.float32) * sx * sw
 
 
-def _rms(x, g, eps):
+def rms(x, g, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
 
 
-def _rope(x, pos, theta):
+def rope(x, pos, theta):
     half = x.shape[-1] // 2
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     ang = pos[:, None, None].astype(jnp.float32) * freqs
@@ -150,94 +153,129 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * c - x2 * s, x1 * s + x2 * c], -1)
 
 
-class Forward:
-    """Reference logits for one configuration and seed."""
+def gqa_attention(h, w, cfg: dict, n_real, int8: bool,
+                  window: Optional[int] = None):
+    """Attention of a normed (t, d) input with weights ``wq wk wv wo``:
+    rotary embedding at positions 0 .. t-1, each query row attending
+    the keys at or before it and before ``n_real`` (the padding after
+    the sequence is never a key), with ``window`` set only the last
+    ``window`` of them, ``num_key_value_heads`` KV heads each shared by
+    a group of query heads; in blocks of QUERY_BLOCK query rows.
+    Returns the output projection, (t, d)."""
+    t = h.shape[0]
+    h_, kv, hd = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+                  cfg["head_dim"])
+    g = h_ // kv
+    theta = cfg["rope_theta"]
+    pos = jnp.arange(t)
+    q = rope(mm(h, w["wq"], int8).reshape(t, h_, hd), pos, theta)
+    k = rope(mm(h, w["wk"], int8).reshape(t, kv, hd), pos, theta)
+    v = mm(h, w["wv"], int8).reshape(t, kv, hd)
+    qb = q.reshape(t // QUERY_BLOCK, QUERY_BLOCK, kv, g, hd)
+    keys = jnp.arange(t)
 
-    def __init__(self, cfg: dict, seed: int, int8: bool = False):
-        self.cfg = cfg
-        self.seed_key = root_key(seed)
-        self.int8 = int8
-        self.dtype = jnp.dtype(cfg["dtype"])
-        self._layer = jax.jit(self._layer_fn)
-        self._embed = jax.jit(self._embed_fn)
-        self._head = jax.jit(self._head_fn)
+    def block(args):
+        bi, qi = args
+        s = jnp.einsum("bkgd,tkd->kgbt", qi, k,
+                       precision=jax.lax.Precision.HIGHEST)
+        s = s / math.sqrt(hd)
+        rows = bi * QUERY_BLOCK + jnp.arange(QUERY_BLOCK)
+        ok = (keys[None, :] <= rows[:, None]) & (keys[None, :] < n_real)
+        if window is not None:
+            ok = ok & (keys[None, :] > rows[:, None] - window)
+        s = jnp.where(ok[None, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("kgbt,tkd->bkgd", p, v,
+                          precision=jax.lax.Precision.HIGHEST)
 
-    def _embed_fn(self, seed_key, tokens):
-        emb = draw(self.cfg, "embed", _leaf_key(seed_key, "embed"),
-                   self.dtype)
-        return emb[tokens].astype(jnp.float32)
+    o = jax.lax.map(block, (jnp.arange(t // QUERY_BLOCK), qb))
+    return mm(o.reshape(t, h_ * hd), w["wo"], int8)
 
-    def _layer_fn(self, seed_key, i, x, n_real):
-        cfg, q8 = self.cfg, self.int8
-        w = jax.tree.map(lambda a: a.astype(jnp.float32),
-                         layer(cfg, seed_key, i, self.dtype))
-        t = x.shape[0]
-        h_, kv, hd = (cfg["num_attention_heads"],
-                      cfg["num_key_value_heads"], cfg["head_dim"])
-        g = h_ // kv
-        eps, theta = cfg["norm_eps"], cfg["rope_theta"]
-        pos = jnp.arange(t)
-        h = _rms(x, w["ln1"], eps)
-        q = _rope(_mm(h, w["wq"], q8).reshape(t, h_, hd), pos, theta)
-        k = _rope(_mm(h, w["wk"], q8).reshape(t, kv, hd), pos, theta)
-        v = _mm(h, w["wv"], q8).reshape(t, kv, hd)
-        qb = q.reshape(t // QUERY_BLOCK, QUERY_BLOCK, kv, g, hd)
-        keys = jnp.arange(t)
 
-        def block(args):
-            bi, qi = args
-            s = jnp.einsum("bkgd,tkd->kgbt", qi, k,
-                           precision=jax.lax.Precision.HIGHEST)
-            s = s / math.sqrt(hd)
-            rows = bi * QUERY_BLOCK + jnp.arange(QUERY_BLOCK)
-            ok = (keys[None, :] <= rows[:, None]) & (keys[None, :] < n_real)
-            s = jnp.where(ok[None, None], s, -jnp.inf)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum("kgbt,tkd->bkgd", p, v,
-                              precision=jax.lax.Precision.HIGHEST)
+def head_logits(x, g, head, eps, int8: bool):
+    """Final RMSNorm (float32 scale ``g``) and the untied head (d, V) in
+    its served dtype, in blocks of VOCAB_BLOCK columns, each cast to
+    float32 as it is used."""
+    x = rms(x, g, eps)
+    v = head.shape[1]
+    nblk = -(-v // VOCAB_BLOCK)
+    pad = nblk * VOCAB_BLOCK - v
+    head = jnp.pad(head, ((0, 0), (0, pad)))
+    blocks = head.reshape(head.shape[0], nblk, VOCAB_BLOCK)
 
-        o = jax.lax.map(block, (jnp.arange(t // QUERY_BLOCK), qb))
-        x = x + _mm(o.reshape(t, h_ * hd), w["wo"], q8)
-        h = _rms(x, w["ln2"], eps)
-        u = jnp.square(jax.nn.relu(_mm(h, w["up"], q8)))
-        return x + _mm(u, w["down"], q8)
+    def one(wb):
+        return mm(x, wb.astype(jnp.float32), int8)
+    out = jax.lax.map(one, jnp.moveaxis(blocks, 1, 0))
+    return jnp.moveaxis(out, 0, 1).reshape(x.shape[0], -1)[:, :v]
 
-    def _head_fn(self, seed_key, x):
-        cfg = self.cfg
-        g = draw(cfg, "final_ln", _leaf_key(seed_key, "final_ln"),
-                 self.dtype).astype(jnp.float32)
-        head = draw(cfg, "lm_head", _leaf_key(seed_key, "lm_head"),
-                    self.dtype)
-        x = _rms(x, g, cfg["norm_eps"])
-        v = head.shape[1]
-        nblk = -(-v // VOCAB_BLOCK)
-        pad = nblk * VOCAB_BLOCK - v
-        head = jnp.pad(head, ((0, 0), (0, pad)))
-        blocks = head.reshape(head.shape[0], nblk, VOCAB_BLOCK)
 
-        def one(wb):
-            return _mm(x, wb.astype(jnp.float32), self.int8)
-        out = jax.lax.map(one, jnp.moveaxis(blocks, 1, 0))
-        return jnp.moveaxis(out, 0, 1).reshape(x.shape[0], -1)[:, :v]
+def forward_logits(tokens: Sequence[int], first: int,
+                   embed: Callable, layer: Callable, num_layers: int,
+                   head: Callable) -> np.ndarray:
+    """Logits (float32) at positions first .. len(tokens)-1, at
+    ``highest`` matmul precision: the tokens padded to a bucket,
+    ``embed(ids)``, ``layer(i, x, n)`` for i in 0 .. num_layers-1 (n,
+    an int32, is the length before the padding), then ``head(rows)`` on
+    the compared rows, padded to a bucket of their own."""
+    n = len(tokens)
+    t = _bucket(n)
+    toks = np.zeros(t, np.int32)
+    toks[:n] = tokens
+    with jax.default_matmul_precision("highest"):
+        x = embed(jnp.asarray(toks))
+        for i in range(num_layers):
+            x = layer(i, x, jnp.int32(n))
+        m = n - first
+        gb = _bucket(m, (64, 128, 256, 512, 1024, 2048, 4096, 8192,
+                         16384))
+        sel = jnp.zeros((gb, x.shape[1]), jnp.float32)
+        sel = sel.at[:m].set(x[first:n])
+        out = head(sel)
+    return np.asarray(out[:m])
 
-    def logits(self, tokens: Sequence[int], first: int) -> np.ndarray:
+
+class LayerwiseForward:
+    """Reference logits for one configuration and seed, from a model
+    module's weights and its layer: ``layer_params(cfg, seed_key, i,
+    dtype)`` and ``top_params(cfg, seed_key, leaf, dtype)`` draw the
+    leaves in the served dtype (the top-level ones are ``embed``,
+    ``final_ln`` and an untied ``lm_head``), and ``block(cfg, w, x,
+    n_real, int8)`` is one layer on the float32 leaves ``w``. The
+    embedding, a layer and the head are one jitted call each, every
+    layer's weights drawn inside its call; ``int8=True`` is the
+    control."""
+
+    def __init__(self, cfg: dict, seed: int, int8: bool,
+                 layer_params: Callable, top_params: Callable,
+                 block: Callable):
+        self.cfg, self.int8 = cfg, int8
+        self.seed_key = W.root_key(seed)
+        dtype = jnp.dtype(cfg["dtype"])
+
+        def embed(seed_key, tokens):
+            emb = top_params(cfg, seed_key, "embed", dtype)
+            return emb[tokens].astype(jnp.float32)
+
+        def layer(seed_key, i, x, n_real):
+            w = jax.tree.map(lambda a: a.astype(jnp.float32),
+                             layer_params(cfg, seed_key, i, dtype))
+            return block(cfg, w, x, n_real, int8)
+
+        def head(seed_key, x):
+            g = top_params(cfg, seed_key, "final_ln",
+                           dtype).astype(jnp.float32)
+            w = top_params(cfg, seed_key, "lm_head", dtype)
+            return head_logits(x, g, w, cfg["norm_eps"], int8)
+        self._embed, self._layer, self._head = (
+            jax.jit(embed), jax.jit(layer), jax.jit(head))
+
+    def logits(self, tokens, first: int):
         """Logits (float32) at positions first .. len(tokens)-1."""
-        n = len(tokens)
-        t = _bucket(n)
-        toks = np.zeros(t, np.int32)
-        toks[:n] = tokens
-        with jax.default_matmul_precision("highest"):
-            x = self._embed(self.seed_key, jnp.asarray(toks))
-            for i in range(self.cfg["num_hidden_layers"]):
-                x = self._layer(self.seed_key, jnp.int32(i), x,
-                                jnp.int32(n))
-            m = n - first
-            gb = _bucket(m, (64, 128, 256, 512, 1024, 2048, 4096, 8192,
-                             16384))
-            sel = jnp.zeros((gb, x.shape[1]), jnp.float32)
-            sel = sel.at[:m].set(x[first:n])
-            out = self._head(self.seed_key, sel)
-        return np.asarray(out[:m])
+        key = self.seed_key
+        return forward_logits(
+            tokens, first, lambda ids: self._embed(key, ids),
+            lambda i, x, n: self._layer(key, jnp.int32(i), x, n),
+            self.cfg["num_hidden_layers"], lambda x: self._head(key, x))
 
 
 def served_sequence(prompt_ids: Sequence[int], served: Sequence[int]):
@@ -258,8 +296,10 @@ def sample_requests(done: Dict[int, dict], seed: int, min_tokens: int,
                     max_requests: int) -> List[int]:
     """Requests to compare: the longest (prompt + served tokens), then
     the longest of each pool and a compressed one where there are any,
-    then others drawn from the seed until ``min_tokens`` served tokens
-    are covered."""
+    then others in an order drawn from the seed until ``min_tokens``
+    served tokens are covered, ``max_requests`` are picked, or none is
+    left. A larger ``max_requests`` changes no pick that a smaller one
+    already carried to ``min_tokens``."""
     if not done:
         return []
     def size(k):

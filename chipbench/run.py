@@ -11,7 +11,9 @@ the cell's open-loop traffic for its warm-up seconds, then measures
 ``--seconds``. ``--trace 0`` reports the end-to-end metrics,
 ``--trace 1`` the per-layer ones (with a profiler trace of a few steady
 seconds). Then the program is freed and what the window served is
-checked against the plain reference (``reference.py``).
+checked against the plain reference: the ``Forward`` of the model module
+that the cell's configuration names (``models/<model>.py``, built from
+``reference.py``'s blocks).
 
 The last line of standard output is the result as JSON. With no
 accelerator, fewer chips than the cell asks for, or a device kind not

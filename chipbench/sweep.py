@@ -109,8 +109,8 @@ def main(argv=None) -> None:
     cfgj = H.load_config(args.config)
     mix = T.load(args.traffic)
     sv = cfgj["serving"]
-    from chipbench import weights as W
-    params = W.program_params(cfgj, args.seed)
+    model = H.load_model(cfgj)
+    params = model.program_params(cfgj, args.seed)
     plan = plan_k_pool(get_workload(mix["plan_workload"]), lam=1000.0,
                        t_slo=0.5, k=sv["pools"])
     for rate in [float(r) for r in args.rates.split(",")]:
@@ -122,7 +122,7 @@ def main(argv=None) -> None:
                              num_blocks=sv.get("num_blocks"))
         t = time.perf_counter()
         rt = FleetRuntime.from_plan(
-            H.program_config(cfgj), params, plan,
+            model.program_config(cfgj), params, plan,
             slots_per_pool=sv["slots"], c_chunk=sv["c_chunk"],
             ctx_scale=mix["scale"], config=scfg)
         H.warm(rt)

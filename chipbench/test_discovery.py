@@ -66,8 +66,9 @@ def test_every_cell_finds_its_files_by_name():
 
 
 def test_every_metric_has_a_reader_that_reads_nothing_from_nothing():
-    rec = H.Record(cfg=H.load_config("minitron-8b"),
-                   peak=H.peaks_for("TPU v5 lite"))
+    cfg = H.load_config("minitron-8b")
+    rec = H.Record(cfg=cfg, peak=H.peaks_for("TPU v5 lite"),
+                   model=H.load_model(cfg))
     rec.counters = {"window": {
         "router": {"compression_attempts": 0, "compression_ms_sum": 0.0},
         "short": {"dispatches": 0, "decode_dispatches": 0}}}

@@ -93,8 +93,8 @@ def test_int8_control_in_the_programs_place_fails(sound):
 def test_a_token_altered_where_it_is_produced_is_caught(monkeypatch):
     build = H.build
 
-    def broken(cfgj, mix, seed):
-        rt, plan = build(cfgj, mix, seed)
+    def broken(cfgj, mix, seed, model):
+        rt, plan = build(cfgj, mix, seed, model)
         for eng in rt.engines.values():
             orig = eng._append_token
 

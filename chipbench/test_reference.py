@@ -1,74 +1,38 @@
-"""The plain reference against the program's own forward pass at a
-small size, the seeded weights, and the int8 control."""
-import jax
+"""The shared parts of the plain reference: tokenizer, routing rule,
+compression faults, the check's sample and the attention block. The
+dense squared-ReLU model's weights and forward pass are tested in
+``test_dense_relu2.py``."""
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import harness as H
 from chipbench import reference as R
-from chipbench import weights as W
 
 SEED = 2 ** 33 + 3
 
 
-def tiny(dtype="float32", layers=2):
-    cfg = H.load_config("minitron-8b")
-    cfg.update(num_hidden_layers=layers, hidden_size=256,
-               num_attention_heads=4, num_key_value_heads=2, head_dim=64,
-               intermediate_size=512, vocab_size=1024, dtype=dtype)
-    return cfg
-
-
-def test_layers_regenerate_bit_for_bit():
-    cfg = tiny()
-    params = W.program_params(cfg, SEED)
-    key = W.root_key(SEED)
-    for i in range(cfg["num_hidden_layers"]):
-        one = W.layer(cfg, key, i, jnp.float32)
-        np.testing.assert_array_equal(params["layers"]["attn"]["wq"][i],
-                                      one["wq"])
-        np.testing.assert_array_equal(params["layers"]["mlp"]["down"][i],
-                                      one["down"])
-        np.testing.assert_array_equal(params["layers"]["ln2"][i], one["ln2"])
-
-
-def test_weights_depend_on_the_whole_seed():
-    cfg = tiny()
-    a = W.program_params(cfg, 5)["lm_head"]
-    b = W.program_params(cfg, 5 + 2 ** 32)["lm_head"]
-    assert not np.array_equal(a, b)
-
-
-def test_program_tree_matches_the_program_init():
-    from repro.models import model as M
-    cfg = tiny()
-    mine = jax.tree.structure(W.program_params(cfg, 1))
-    theirs = jax.tree.structure(jax.eval_shape(
-        lambda: M.init_params(H.program_config(cfg), jax.random.key(0))))
-    assert mine == theirs
-
-
-def test_reference_equals_program_forward_in_float32():
-    from repro.models import model as M
-    cfg = tiny()
-    params = W.program_params(cfg, SEED)
-    toks = np.random.default_rng(0).integers(1, 1024, 300).tolist()
-    prog, _ = M.forward(params, H.program_config(cfg),
-                        {"tokens": jnp.asarray([toks])})
-    ref = R.Forward(cfg, SEED).logits(toks, 0)
-    prog = np.asarray(prog[0])
-    assert np.max(np.abs(prog - ref)) <= 1e-4 * np.max(np.abs(ref))
-    np.testing.assert_array_equal(R.gaps(ref, prog.argmax(-1)), 0.0)
-
-
-def test_int8_control_moves_tokens_the_reference_would_not():
-    cfg = tiny()
-    toks = np.random.default_rng(1).integers(1, 1024, 400).tolist()
-    ref = R.Forward(cfg, SEED).logits(toks, 0)
-    ctl = R.Forward(cfg, SEED, int8=True).logits(toks, 0)
-    g = R.gaps(ref, ctl.argmax(-1))
-    assert g.max() > 0.0
-    assert np.mean(g > 0) > 0.01
+def test_window_attention_sees_only_the_last_keys():
+    # one head, one KV head: with ``window`` w, row r attends keys
+    # r-w+1 .. r; without, 0 .. r; never a key at or past n_real
+    t, hd, n_real, w = 2 * R.QUERY_BLOCK, 8, 300, 5
+    rng = np.random.default_rng(0)
+    eye = np.eye(hd, dtype=np.float32)
+    h = jnp.asarray(rng.normal(size=(t, hd)), jnp.float32)
+    wts = {k: jnp.asarray(eye) for k in ("wq", "wk", "wv", "wo")}
+    cfg = {"num_attention_heads": 1, "num_key_value_heads": 1,
+           "head_dim": hd, "rope_theta": 10000.0}
+    pos = jnp.arange(t)
+    q = R.rope(h.reshape(t, 1, hd), pos, 10000.0)[:, 0]
+    k = q
+    s = np.asarray(q @ k.T) / np.sqrt(hd)
+    for window in (None, w):
+        got = np.asarray(R.gqa_attention(h, wts, cfg, n_real, False,
+                                         window=window))
+        for r in (0, 3, 200, 299):
+            lo = 0 if window is None else max(0, r - window + 1)
+            keys = np.arange(lo, min(r, n_real - 1) + 1)
+            p = np.exp(s[r, keys] - s[r, keys].max())
+            want = (p / p.sum()) @ np.asarray(h)[keys]
+            np.testing.assert_allclose(got[r], want, rtol=1e-5, atol=1e-5)
 
 
 def test_served_sequence_repeats_the_last_prompt_token():
@@ -127,3 +91,45 @@ def test_sample_takes_the_longest_each_pool_and_a_compressed_one():
     pick = R.sample_requests(done, SEED, 40, 6)
     assert pick[0] == 19 and 50 in pick and 3 in pick
     assert R.sample_requests(done, SEED, 40, 6) == pick
+
+
+def done_mix(n, short_len):
+    """Finished requests: every third of mixed length, the rest of
+    ``short_len`` served tokens; one long-pool request in 17 and one
+    compressed."""
+    done = {}
+    for i in range(n):
+        served = short_len if i % 3 else 8 + (i * 37) % 90
+        done[1000 + i] = {"prompt_ids": [1] * (20 + (i * 53) % 400),
+                          "served": [2] * served,
+                          "pool": "long" if i % 17 == 5 else "short",
+                          "compressed": i == 11}
+    return done
+
+
+def test_sample_that_reaches_its_tokens_in_32_is_unchanged():
+    # the pick of the 32-request rule on this seed, which covers 621
+    # tokens with 15 requests; the 64-request rule must give the same
+    done = done_mix(80, 30)
+    pinned = [1075, 1022, 1011, 1062, 1038, 1024, 1019, 1002, 1065, 1049,
+              1043, 1012, 1050, 1007, 1014]
+    assert R.sample_requests(done, 4515000003, 600, 32) == pinned
+    assert R.sample_requests(done, 4515000003, 600, 64) == pinned
+
+
+def test_sample_of_short_requests_draws_past_32_to_its_tokens():
+    # mostly 8-token requests, as azure's: 32 draws cover under 600
+    # tokens, 64 do
+    done = done_mix(90, 8)
+    for i, k in enumerate(done):
+        done[k]["served"] = [2] * (30 if i % 7 == 0 else 8)
+    def tokens(pick):
+        return sum(len(done[k]["served"]) for k in pick)
+    old = R.sample_requests(done, 4515000003, 600, 32)
+    new = R.sample_requests(done, 4515000003, 600, 64)
+    assert len(old) == 32 and tokens(old) < 600
+    assert new[:32] == old and 32 < len(new) <= 64
+    assert tokens(new) >= 600 and tokens(new[:-1]) < 600
+    # never more than there are
+    few = {k: done[k] for k in list(done)[:40]}
+    assert sorted(R.sample_requests(few, 1, 10 ** 6, 64)) == sorted(few)

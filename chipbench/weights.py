@@ -4,41 +4,27 @@ from the same keys, so the reference takes nothing the program made.
 
 Every matrix is normal(0, s) with s the power of two nearest
 1/sqrt(fan_in), every norm scale 1 + k/64 with k a rounded
-normal(0, 8) clipped to +-24, each drawn in float32 from its own key
-and cast to the served dtype. Scaling by a power of two and small
-integer arithmetic are exact, so however the compiler fuses or
-reassociates them no bit changes. A layer's leaves use
-``fold_in(leaf key, layer)``, so the reference can regenerate one layer
-at a time, bit for bit, from what the one jitted call for the whole
-model made.
+normal(0, 8) clipped to +-24, an embedding normal(0, 1), each drawn in
+float32 from its own key and cast to the served dtype. Scaling by a
+power of two and small integer arithmetic are exact, so however the
+compiler fuses or reassociates them no bit changes.
 
-``program_params`` lays the numbers out in the program's parameter tree
-(``repro.models.model``: layers stacked on a leading axis, projections
-as (in, out) matrices); that layout is the one thing this file knows of
-the program.
+A model module (``models/<name>.py``) names its leaves in one ordered
+table (``Table``); a leaf's key is ``fold_in(seed key, its index in
+that table)``, and a layer's leaf folds in the layer index besides (a
+module may fold in more, such as an expert index), so the reference can
+regenerate one layer at a time, bit for bit, from what the one jitted
+call for the whole model made. What the leaves are, their shapes and
+the program's parameter tree are the module's business.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
-from typing import Dict
+from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-
-# leaf name -> (shape as a function of the config, fan-in, kind);
-# kind "w" = matrix, "norm" = scale vector
-LAYER_LEAVES = ("ln1", "wq", "wk", "wv", "wo", "ln2", "up", "down")
-TOP_LEAVES = ("embed", "final_ln", "lm_head")
-
-
-def _shape(cfg: dict, leaf: str):
-    d, h, kv, hd, ff, v = (cfg["hidden_size"], cfg["num_attention_heads"],
-                           cfg["num_key_value_heads"], cfg["head_dim"],
-                           cfg["intermediate_size"], cfg["vocab_size"])
-    return {"ln1": (d,), "ln2": (d,), "final_ln": (d,),
-            "wq": (d, h * hd), "wk": (d, kv * hd), "wv": (d, kv * hd),
-            "wo": (h * hd, d), "up": (d, ff), "down": (ff, d),
-            "embed": (v, d), "lm_head": (d, v)}[leaf]
 
 
 def root_key(seed: int):
@@ -46,47 +32,46 @@ def root_key(seed: int):
     return jax.random.fold_in(k, (int(seed) >> 32) & 0x7FFFFFFF)
 
 
-def _leaf_key(seed_key, leaf: str):
-    names = TOP_LEAVES + LAYER_LEAVES
-    return jax.random.fold_in(seed_key, names.index(leaf))
+def leaf_key(seed_key, leaves: Sequence[str], leaf: str):
+    """The key of ``leaf``, by its place in the module's leaf table."""
+    return jax.random.fold_in(seed_key, list(leaves).index(leaf))
 
 
-def draw(cfg: dict, leaf: str, key, dtype):
-    shape = _shape(cfg, leaf)
+def draw(key, shape, kind: str, dtype):
+    """One leaf: ``kind`` "matrix" (fan-in ``shape[0]``), "norm" or
+    "embed"."""
     x = jax.random.normal(key, shape, jnp.float32)
-    if leaf in ("ln1", "ln2", "final_ln"):
+    if kind == "norm":
         x = 1.0 + jnp.round(jnp.clip(8.0 * x, -24.0, 24.0)) / 64.0
-    elif leaf != "embed":
+    elif kind == "matrix":
         x = x * 2.0 ** -round(math.log2(math.sqrt(shape[0])))
+    elif kind != "embed":
+        raise ValueError(f"unknown leaf kind {kind!r}")
     return x.astype(dtype)
 
 
-def layer(cfg: dict, seed_key, i, dtype) -> Dict[str, jax.Array]:
-    """All leaves of layer ``i`` (traced or concrete index)."""
-    return {n: draw(cfg, n, jax.random.fold_in(_leaf_key(seed_key, n), i),
-                    dtype) for n in LAYER_LEAVES}
+@dataclasses.dataclass(frozen=True)
+class Table:
+    """A module's leaves: the top-level ones, then those of every layer,
+    in one order (a leaf's key is its index in ``top + layer``, so the
+    order is part of the weights); which are norm scales; and
+    ``shape(cfg, leaf)``. Every other leaf but ``embed`` is a matrix."""
+    top: tuple
+    layer: tuple
+    norms: tuple
+    shape: Callable
 
+    def _draw(self, cfg: dict, leaf: str, key, dtype):
+        kind = "norm" if leaf in self.norms else \
+            "embed" if leaf == "embed" else "matrix"
+        return draw(key, self.shape(cfg, leaf), kind, dtype)
 
-def top(cfg: dict, seed_key, leaf: str, dtype):
-    return draw(cfg, leaf, _leaf_key(seed_key, leaf), dtype)
+    def layer_params(self, cfg: dict, seed_key, i, dtype):
+        """All leaves of layer ``i`` (traced or concrete index)."""
+        leaves = self.top + self.layer
+        return {n: self._draw(cfg, n, jax.random.fold_in(
+            leaf_key(seed_key, leaves, n), i), dtype) for n in self.layer}
 
-
-def program_params(cfg: dict, seed: int):
-    """The whole model in the program's parameter tree, made on the
-    device by one jitted call, in the served dtype."""
-    dtype = jnp.dtype(cfg["dtype"])
-
-    def make(seed_key):
-        stacked = jax.vmap(lambda i: layer(cfg, seed_key, i, dtype))(
-            jnp.arange(cfg["num_hidden_layers"]))
-        return {
-            "embed": top(cfg, seed_key, "embed", dtype),
-            "final_ln": top(cfg, seed_key, "final_ln", dtype),
-            "lm_head": top(cfg, seed_key, "lm_head", dtype),
-            "layers": {
-                "ln1": stacked["ln1"], "ln2": stacked["ln2"],
-                "attn": {k: stacked[k] for k in ("wq", "wk", "wv", "wo")},
-                "mlp": {"up": stacked["up"], "down": stacked["down"]},
-            },
-        }
-    return jax.jit(make)(root_key(seed))
+    def top_params(self, cfg: dict, seed_key, leaf: str, dtype):
+        return self._draw(cfg, leaf, leaf_key(seed_key, self.top
+                                              + self.layer, leaf), dtype)
