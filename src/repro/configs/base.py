@@ -28,6 +28,14 @@ class MoEConfig:
     capacity_factor: float = 1.25
     # d_ff of each expert (may differ from the dense d_ff)
     expert_d_ff: int = 0
+    # router: "softmax" or "sigmoid" scores over all experts; the top-k
+    # gates are normalised to sum 1, then scaled
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # the experts this chip holds, ``first_expert`` .. + ``experts_held``
+    # - 1 of ``num_experts`` (0 = all): one expert-parallel rank's share
+    first_expert: int = 0
+    experts_held: int = 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,6 +73,13 @@ class ModelConfig:
     head_dim: int = 0                     # 0 -> d_model // num_heads
     # attention behaviour
     attention_window: int = 0             # 0 = full attention; >0 = sliding window
+    # per-layer sliding windows (0 = full attention), one per layer; ()
+    # = every layer ``attention_window``. Read by the paged path only.
+    layer_windows: Tuple[int, ...] = ()
+    # leading layers whose MLP is dense (``dense_d_ff`` wide) in a MoE
+    # model (``first_k_dense_replace``). Read by the paged path only.
+    dense_layers: int = 0
+    dense_d_ff: int = 0
     qkv_bias: bool = False
     activation: str = "silu"              # silu | squared_relu | gelu
     rope_theta: float = 500000.0

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.expert_gmm import expert_gmm as _expert_gmm
 from repro.kernels.gqa_decode import gqa_decode as _gqa_pallas
 from repro.kernels.paged_decode import paged_gqa_decode as _paged_pallas
 from repro.kernels.textrank import textrank_pallas
@@ -42,11 +43,19 @@ def gqa_decode(q, k_cache, v_cache, valid, active=None, block_s: int = 512):
 
 
 def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
-                     active=None):
-    """Paged flash-decode attention over a block-table-indexed KV pool;
-    see kernels/paged_decode.py. Inactive rows return exact zeros."""
+                     active=None, window=None):
+    """Paged flash-decode attention over a block-table-indexed KV pool,
+    optionally over a static sliding ``window`` of keys; see
+    kernels/paged_decode.py. Inactive rows return exact zeros."""
     return _paged_pallas(q, k_pages, v_pages, block_tables, seq_lens,
-                         active, interpret=interpret_mode())
+                         active, interpret=interpret_mode(), window=window)
+
+
+def expert_gmm(x, w, tile_expert, live_tiles, tm, out_dtype):
+    """Grouped matmul of expert-sorted row tiles; see
+    kernels/expert_gmm.py."""
+    return _expert_gmm(x, w, tile_expert, live_tiles, tm=tm,
+                       out_dtype=out_dtype, interpret=interpret_mode())
 
 
 def textrank_scores(sim: np.ndarray, damping: float = 0.85,

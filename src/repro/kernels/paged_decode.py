@@ -37,6 +37,13 @@ software parameter" move: the dense engine reserves a worst-case
 the *actual* length mix (profiles.n_max_paged) and the block table
 absorbs the indirection.
 
+With a static sliding ``window`` (a windowed layer of a model whose
+layers mix window and full attention) a row attends only its last
+``window`` keys: its sweep starts at the page holding position
+``seq_len - window`` and masks the tokens before it, so it copies at
+most ``ceil((window + BLOCK_S - 1) / BLOCK_S)`` pages whatever its
+context, and its compute block is cut to about that size.
+
 Validated in interpret mode against
 ``repro.kernels.ref.paged_gqa_decode_ref``.
 """
@@ -56,11 +63,18 @@ BLOCK_BYTES = 2 << 20          # K bytes of one compute block (V the same)
 
 
 def pages_per_block(nb: int, block_s: int, hkv: int, hd: int,
-                    itemsize: int) -> int:
+                    itemsize: int, window=None) -> int:
     """Pages per compute block: as many as hold ``BLOCK_BYTES`` of K,
-    at least one, at most the block table's ``nb``."""
+    at least one, at most the block table's ``nb``; with a ``window``,
+    no more than the pages a window can span, rounded up to whole
+    128-token lane tiles."""
     page_bytes = block_s * hkv * hd * itemsize
-    return max(1, min(nb, BLOCK_BYTES // page_bytes))
+    ppb = max(1, min(nb, BLOCK_BYTES // page_bytes))
+    if window is not None:
+        span = pl.cdiv(window + block_s - 1, block_s)
+        tile = max(1, 128 // block_s)
+        ppb = min(ppb, pl.cdiv(span, tile) * tile)
+    return ppb
 
 
 def _heads(block, hkv: int, tokens: int):
@@ -82,10 +96,17 @@ def _heads(block, hkv: int, tokens: int):
 def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                          k_buf, v_buf, sems, m_ref, l_ref, acc_ref, st_ref,
                          *, scale: float, slots: int, nb: int, block_s: int,
-                         ppb: int):
+                         ppb: int, window):
     b = pl.program_id(0)
     hkv = k_buf.shape[3]
     tokens = ppb * block_s
+
+    def first_page(slot):
+        """The slot's first page in its sweep: 0, or with a window the
+        page holding its first key inside it."""
+        if window is None:
+            return 0
+        return jnp.maximum(len_ref[slot] - window, 0) // block_s
 
     @pl.when(b == 0)
     def _init_state():
@@ -95,7 +116,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     def pages(slot, blk, buf, wait: bool):
         """Start (or wait for) the copies of block ``blk`` of ``slot``:
         its live pages only."""
-        first = blk * ppb
+        first = blk * ppb if window is None else first_page(slot) + blk * ppb
         n = jnp.minimum(ppb, pl.cdiv(len_ref[slot], block_s) - first)
 
         def one(p, carry):
@@ -112,7 +133,11 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         lax.fori_loop(0, n, one, 0)
 
     seq_len = len_ref[b]
-    n_blocks = pl.cdiv(pl.cdiv(seq_len, block_s), ppb)
+    if window is None:
+        n_blocks = pl.cdiv(pl.cdiv(seq_len, block_s), ppb)
+    else:
+        start = first_page(b)
+        n_blocks = pl.cdiv(pl.cdiv(seq_len, block_s) - start, ppb)
 
     @pl.when(seq_len > 0)
     def _sweep():
@@ -141,11 +166,18 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                 pages(nxt, 0, nbuf, wait=False)
 
             pages(b, i, buf, wait=True)
-            base = i * tokens
-            live_col = base + lax.broadcasted_iota(jnp.int32, (tokens, 1), 0) \
-                < seq_len
-            live_row = base + lax.broadcasted_iota(jnp.int32, (1, tokens), 1) \
-                < seq_len
+            if window is None:
+                base = i * tokens
+                live_col = base + lax.broadcasted_iota(
+                    jnp.int32, (tokens, 1), 0) < seq_len
+                live_row = base + lax.broadcasted_iota(
+                    jnp.int32, (1, tokens), 1) < seq_len
+            else:
+                base = start * block_s + i * tokens
+                col = base + lax.broadcasted_iota(jnp.int32, (tokens, 1), 0)
+                row = base + lax.broadcasted_iota(jnp.int32, (1, tokens), 1)
+                live_col = (col < seq_len) & (col >= seq_len - window)
+                live_row = (row < seq_len) & (row >= seq_len - window)
             ks = _heads(k_buf.at[buf], hkv, tokens)
             vs = _heads(v_buf.at[buf], hkv, tokens)
             for g, (k, v) in enumerate(zip(ks, vs)):
@@ -175,22 +207,26 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[...] = jnp.zeros_like(o_ref)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
-                     active=None, *, interpret: bool):
+                     active=None, *, interpret: bool, window=None):
     """q: (B, H, hd); k_pages/v_pages: (P, BLOCK_S, Hkv, hd) shared
     physical block pool (token-major, the cache layout); block_tables:
     (B, NB) int32 logical->physical block map, read only below each
-    row's live length; seq_lens: (B,) int32 valid tokens per slot
-    (pos + 1); active: optional (B,) bool — rows with active=False
-    copy nothing and return zeros. Returns (B, H*hd). ``interpret=True``
-    runs the kernel body in Python (CPU validation);
-    ``interpret=False`` lowers it for the TPU."""
+    row's live length (and, with a ``window``, only from the page
+    holding its first key in the window); seq_lens: (B,) int32 valid
+    tokens per slot (pos + 1); active: optional (B,) bool — rows with
+    active=False copy nothing and return zeros; window: None (every
+    key) or a static sliding window, the row's last ``window`` keys.
+    Returns (B, H*hd). ``interpret=True`` runs the kernel body in
+    Python (CPU validation); ``interpret=False`` lowers it for the
+    TPU."""
     b, h, hd = q.shape
     block_s, hkv = k_pages.shape[1], k_pages.shape[2]
     nb = block_tables.shape[1]
     qpk = h // hkv
-    ppb = pages_per_block(nb, block_s, hkv, hd, k_pages.dtype.itemsize)
+    ppb = pages_per_block(nb, block_s, hkv, hd, k_pages.dtype.itemsize,
+                          window)
 
     qg = q.reshape(b, hkv, qpk, hd)
     bt = block_tables.astype(jnp.int32).reshape(b * nb)
@@ -220,7 +256,8 @@ def paged_gqa_decode(q, k_pages, v_pages, block_tables, seq_lens,
     )
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, scale=1.0 / math.sqrt(hd),
-                          slots=b, nb=nb, block_s=block_s, ppb=ppb),
+                          slots=b, nb=nb, block_s=block_s, ppb=ppb,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, qpk, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

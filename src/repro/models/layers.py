@@ -489,13 +489,15 @@ def scatter_slot_row(leaf, row, s: int, axis: int):
 
 def paged_decode_attention(p: Params, cfg: ModelConfig, x, kv,
                            block_tables, pos, decode_impl: str = "xla",
-                           active=None):
+                           active=None, window=None):
     """Single-token decode against a paged KV cache (one layer's block
     pool). x: (B,1,D); kv: {"k","v"} (P, BLOCK_S, Hkv, hd);
     block_tables: (B, NB) int32; pos: (B,) absolute position of the new
-    token. Math is identical to :func:`decode_attention` on the
-    gathered pages, so paged decode reproduces dense decode
-    token-for-token. Returns (out, new_kv)."""
+    token; window: None, or a static sliding window (the new token
+    attends the last ``window`` positions, itself included). Math is
+    identical to :func:`decode_attention` on the gathered pages, so
+    paged decode reproduces dense decode token-for-token. Returns (out,
+    new_kv)."""
     b = x.shape[0]
     p_blocks, bs = kv["k"].shape[0], kv["k"].shape[1]
     nb = block_tables.shape[1]
@@ -515,12 +517,15 @@ def paged_decode_attention(p: Params, cfg: ModelConfig, x, kv,
     if decode_impl == "pallas":
         from repro.kernels import ops as kops
         out = kops.paged_gqa_decode(q[:, 0], new_kv["k"], new_kv["v"],
-                                    block_tables, pos + 1, active)
+                                    block_tables, pos + 1, active,
+                                    window=window)
         out = out.reshape(b, 1, -1)
     else:
         k_all = gather_pages(new_kv["k"], block_tables)
         v_all = gather_pages(new_kv["v"], block_tables)
         valid = jnp.arange(nb * bs)[None, :] <= pos[:, None]
+        if window is not None:
+            valid &= jnp.arange(nb * bs)[None, :] > pos[:, None] - window
         out = _sdpa(q, k_all, v_all, valid[:, None, :], cfg.q_per_kv)
     return out @ p["wo"], new_kv
 

@@ -222,6 +222,7 @@ def forward(params: Params, cfg: ModelConfig, batch: Dict,
             parallel=None, window: Optional[int] = None
             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (logits, aux_loss). Teacher-forcing full-sequence pass."""
+    _paged_only(cfg)
     tokens = batch["tokens"]
     b, s_len = tokens.shape
     x = params["embed"][tokens]
@@ -341,6 +342,15 @@ def _ssm_forward(params, cfg, x):
     return x
 
 
+def _paged_only(cfg: ModelConfig) -> None:
+    """Per-layer windows and leading dense layers exist in the paged
+    serving steps only."""
+    if cfg.layer_windows or cfg.dense_layers:
+        raise NotImplementedError(
+            f"{cfg.name}: per-layer windows and leading dense layers are "
+            "served through the paged KV cache only (paged=True)")
+
+
 # ===========================================================================
 # decode path (serve_step)
 # ===========================================================================
@@ -365,6 +375,7 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
     to_named) — KV leaves are created directly under their sharding
     (kv-head dim over the model axis for the sharded serving engine);
     subtrees built by reshape/broadcast are device_put after."""
+    _paged_only(cfg)
     dtype = jnp.dtype(cfg.dtype)
     fam = cfg.family
     sh = shardings or {}
@@ -437,22 +448,122 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int,
     HBM scales with the ACTUAL length mix, not batch * c_max.
 
     Supported for the contiguous-cache attention families (dense/MoE,
-    full attention, fp KV) — the paper's serving model (Llama-3-70B).
+    fp KV) — the paper's serving model (Llama-3-70B) — with full
+    attention or per-layer sliding windows (``cfg.layer_windows``: a
+    windowed layer pages every position like a full one and attends
+    only its window; the pages behind it stay allocated, since all
+    layers share one block table). Leading dense layers of a MoE model
+    (``cfg.dense_layers``) keep their pool under "kv_dense".
     ``shardings``: optional cache-shaped pytree of jax shardings (the
     sharded engine's kv-head-split block pool).
     """
     if cfg.family not in (DENSE, MOE) or cfg.mla is not None:
         raise NotImplementedError(
-            "paged KV cache supports dense/MoE full-attention models; "
+            "paged KV cache supports dense/MoE attention models; "
             f"family={cfg.family!r} mla={cfg.mla is not None}")
     if cfg.attention_window:
         raise NotImplementedError(
             "windowed attention already bounds KV by the window; paging "
             "it would page a ring buffer — unsupported")
     sh = shardings or {}
-    return {"kv": L.init_paged_kv_cache(cfg, cfg.num_layers, num_blocks,
-                                        block_size,
-                                        shardings=sh.get("kv"))}
+    cache = {"kv": L.init_paged_kv_cache(
+        cfg, cfg.num_layers - cfg.dense_layers, num_blocks, block_size,
+        shardings=sh.get("kv"))}
+    if cfg.dense_layers:
+        cache["kv_dense"] = L.init_paged_kv_cache(
+            cfg, cfg.dense_layers, num_blocks, block_size,
+            shardings=sh.get("kv_dense"))
+    return cache
+
+
+def _period(windows) -> Tuple:
+    """The shortest run of windows (0 -> None, full attention) that
+    repeats to give ``windows``."""
+    n = len(windows)
+    for p in range(1, n + 1):
+        if n % p == 0 and all(windows[i] == windows[i % p]
+                              for i in range(n)):
+            return tuple(w or None for w in windows[:p])
+
+
+def _period_scan(layer, x, stacked, kv, windows):
+    """``layer(x, lp, kv, window) -> (x, kv)`` over stacked layers and
+    their KV pools, in periods of ``len(windows)`` layers, each
+    period's layers unrolled with their static windows. One period of
+    one layer is the plain layer scan. Expert matrices stay whole: a
+    layer gets all layers' ``w_gate`` / ``w_up`` / ``w_down`` and its
+    index ``layer`` (the grouped-matmul kernel indexes them; a slice
+    would be copied for it)."""
+    if "moe" in stacked:
+        whole = {n: stacked["moe"][n] for n in MOE_MOD.EXPERT_LEAVES}
+        rest = {n: a for n, a in stacked["moe"].items() if n not in whole}
+        rest["layer"] = jnp.arange(kv["k"].shape[0])
+        stacked = dict(stacked, moe=rest)
+        inner = layer
+
+        def layer(x, lp, kv, window):
+            return inner(x, dict(lp, moe=dict(lp["moe"], **whole)), kv,
+                         window)
+    p = len(windows)
+    if kv["k"].shape[0] == p:
+        # one period: no loop; each layer reads static slices and
+        # writes its own pool in place
+        for j, w in enumerate(windows):
+            x, new = layer(x, jax.tree.map(lambda a: a[j], stacked),
+                           jax.tree.map(lambda a: a[j], kv), w)
+            kv = jax.tree.map(lambda a, b: a.at[j].set(b), kv, new)
+        return x, kv
+    if p == 1:
+        def body(x, inp):
+            return layer(x, *inp, windows[0])
+        return _scan(body, x, (stacked, kv))
+    fold = lambda a: a.reshape(a.shape[0] // p, p, *a.shape[1:])
+
+    def body(x, inp):
+        lps, kvs = inp
+        new = []
+        for j, w in enumerate(windows):
+            x, kv_j = layer(x, jax.tree.map(lambda a: a[j], lps),
+                            jax.tree.map(lambda a: a[j], kvs), w)
+            new.append(kv_j)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *new)
+    x, kv = _scan(body, x, jax.tree.map(fold, (stacked, kv)))
+    return x, jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), kv)
+
+
+def _paged_stack(params: Params, cfg: ModelConfig, x, cache: Params,
+                 layer) -> Tuple[jnp.ndarray, Params]:
+    """The paged steps' layer stack: the leading dense layers
+    (``cfg.dense_layers``), then the rest in periods of their
+    ``cfg.layer_windows``. ``layer(x, lp, kv, window)`` is one layer."""
+    k = cfg.dense_layers
+    windows = cfg.layer_windows or (0,) * cfg.num_layers
+    cache = dict(cache)
+    if k:
+        x, cache["kv_dense"] = _period_scan(
+            layer, x, params["dense_layers"], cache["kv_dense"],
+            _period(windows[:k]))
+    x, cache["kv"] = _period_scan(layer, x, params["layers"], cache["kv"],
+                                  _period(windows[k:]))
+    return x, cache
+
+
+def _paged_ffn(lp, cfg: ModelConfig, h, parallel, mode: str, valid):
+    """The layer's MLP: dense, the dropless held-expert layer on one
+    device, or the expert-parallel capacity layer over a mesh."""
+    if "mlp" in lp:
+        return L.mlp(lp["mlp"], cfg, h)
+    if parallel is None:
+        return MOE_MOD.moe_held(lp["moe"], cfg, h, valid)
+    # the capacity layer takes this layer's experts out of the stacks
+    p = {n: a[lp["moe"]["layer"]] if n in MOE_MOD.EXPERT_LEAVES else a
+         for n, a in lp["moe"].items() if n != "layer"}
+    m, _ = MOE_MOD.moe_block_sharded(p, cfg, h, parallel, mode=mode)
+    return m
+
+
+def _attn_scope(window):
+    return jax.named_scope("attn.window" if window else "attn.full")
 
 
 def paged_decode_step(params: Params, cfg: ModelConfig, token,
@@ -467,29 +578,20 @@ def paged_decode_step(params: Params, cfg: ModelConfig, token,
     x = params["embed"][token]
     pos = jnp.asarray(pos, jnp.int32)
     block_tables = jnp.asarray(block_tables, jnp.int32)
+    valid = None if active is None else active[:, None]
 
-    def body(x, inp):
-        lp, kv = inp
+    def layer(x, lp, kv, window):
         h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        a, new = L.paged_decode_attention(lp["attn"], cfg, h, kv,
-                                          block_tables, pos,
-                                          decode_impl=decode_impl,
-                                          active=active)
+        with _attn_scope(window):
+            a, new = L.paged_decode_attention(lp["attn"], cfg, h, kv,
+                                              block_tables, pos,
+                                              decode_impl=decode_impl,
+                                              active=active, window=window)
         x = x + a
         h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            if parallel is None:
-                m, _ = MOE_MOD.moe_block(lp["moe"], cfg, h, None)
-            else:
-                m, _ = MOE_MOD.moe_block_sharded(lp["moe"], cfg, h, parallel,
-                                                 mode="psum")
-            x = x + m
-        else:
-            x = x + L.mlp(lp["mlp"], cfg, h)
-        return x, new
+        return x + _paged_ffn(lp, cfg, h, parallel, "psum", valid), new
 
-    x, kv = _scan(body, x, (params["layers"], cache["kv"]))
-    cache = dict(cache, kv=kv)
+    x, cache = _paged_stack(params, cfg, x, cache, layer)
     x = L.rmsnorm(x, params["final_ln"], cfg.norm_eps)
     head = params.get("lm_head")
     logits = x @ head if head is not None else x @ params["embed"].T
@@ -520,35 +622,31 @@ def paged_prefill_chunk(params: Params, cfg: ModelConfig, tokens,
     block_tables = jnp.asarray(block_tables, jnp.int32)
     x = params["embed"][tokens]                          # (B, L, D)
     positions = start_pos[:, None] + jnp.arange(l)[None, :]
+    rows = jnp.arange(l)[None, :] < lengths[:, None]      # live tokens
 
-    def body(x, inp):
-        lp, kv = inp
+    def layer(x, lp, kv, window):
         h = L.rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = L._qkv(lp["attn"], cfg, h, h)
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
-        kv = L.write_chunk_kv_paged(kv, k, v, block_tables, start_pos,
-                                    lengths)
-        k_all = L.gather_pages(kv["k"], block_tables)
-        v_all = L.gather_pages(kv["v"], block_tables)
-        s_max = k_all.shape[1]
-        valid = jnp.arange(s_max)[None, None, :] <= positions[:, :, None]
-        a = L._sdpa(q, k_all, v_all, valid, cfg.q_per_kv)
-        x = x + a @ lp["attn"]["wo"]
+        with _attn_scope(window):
+            q, k, v = L._qkv(lp["attn"], cfg, h, h)
+            q = L.apply_rope(q, positions, cfg.rope_theta)
+            k = L.apply_rope(k, positions, cfg.rope_theta)
+            kv = L.write_chunk_kv_paged(kv, k, v, block_tables, start_pos,
+                                        lengths)
+            k_all = L.gather_pages(kv["k"], block_tables)
+            v_all = L.gather_pages(kv["v"], block_tables)
+            s_max = k_all.shape[1]
+            keys = jnp.arange(s_max)[None, None, :]
+            valid = keys <= positions[:, :, None]
+            if window is not None:
+                # a band: key j is visible to query i when
+                # i - window < j <= i
+                valid &= keys > positions[:, :, None] - window
+            a = L._sdpa(q, k_all, v_all, valid, cfg.q_per_kv)
+            x = x + a @ lp["attn"]["wo"]
         h = L.rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.moe is not None:
-            if parallel is None:
-                m, _ = MOE_MOD.moe_block(lp["moe"], cfg, h, None)
-            else:
-                m, _ = MOE_MOD.moe_block_sharded(lp["moe"], cfg, h, parallel,
-                                                 mode="a2a")
-            x = x + m
-        else:
-            x = x + L.mlp(lp["mlp"], cfg, h)
-        return x, kv
+        return x + _paged_ffn(lp, cfg, h, parallel, "a2a", rows), kv
 
-    x, kv = _scan(body, x, (params["layers"], cache["kv"]))
-    cache = dict(cache, kv=kv)
+    x, cache = _paged_stack(params, cfg, x, cache, layer)
     head = params.get("lm_head")
     if all_logits:
         # speculative verify path (paged_verify_step): logits at every

@@ -12,6 +12,11 @@ whose dispatch tensor would be TBs at the assigned shapes.
 
 The same code runs without a mesh (ep_axis=None -> ep_size=1, the
 all_to_all degenerates to identity) for CPU smoke tests.
+
+Serving on one device takes the dropless layer instead
+(:func:`moe_held`): no capacity, so a request's output never depends on
+its batch-mates, over the experts the chip holds (all of them, or one
+expert-parallel rank's share).
 """
 from __future__ import annotations
 
@@ -262,3 +267,99 @@ def _moe_tokens(p: Dict, cfg: ModelConfig, tokens, ep_axis: Optional[str]):
     y = y.at[flat_tok[order]].add(pair_out * flat_g[order][:, None])
     aux = {"lb_loss": lb_loss, "router_fraction": fe}
     return y, aux
+
+
+# ------------------------------------------------------------ dropless
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+def moe_held(p: Dict, cfg: ModelConfig, x, valid=None):
+    """One chip's share of a dropless expert layer. The router scores
+    every token over all ``num_experts`` experts at its published width
+    (f32, ``softmax`` or ``sigmoid``), keeps the top ``top_k``,
+    normalises their gates to sum 1 and scales them
+    (``routed_scale``); the chip then computes only the (token, expert)
+    pairs whose expert it holds, ``first_expert`` onwards
+    (``experts_held``, or all), with no capacity: nothing is dropped,
+    so a row's output never depends on the other rows. The held experts
+    run as one grouped matmul over the pairs sorted by expert
+    (``kernels/expert_gmm.py``); the shared expert is added once. What
+    experts held elsewhere would add is left out. ``valid``: optional
+    bool mask over x's leading dims; rows outside it (a bucketed
+    chunk's padding, idle slots) are routed to no expert.
+
+    p: "router" (D, E) f32; "w_gate", "w_up" (held, D, F), "w_down"
+    (held, F, D), or each of these stacked over layers with "layer"
+    this layer's index; optional "shared" MLP. x: (..., D)."""
+    mc = cfg.moe
+    held = mc.experts_held or mc.num_experts
+    assert p["w_up"].shape[-3] == held, (p["w_up"].shape, held)
+    shape = x.shape
+    h = x.reshape(-1, shape[-1])
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(h.astype(jnp.float32), p["router"],
+                         precision=jax.lax.Precision.HIGHEST)
+        score = (jax.nn.sigmoid(logits) if mc.scoring == "sigmoid"
+                 else jax.nn.softmax(logits, axis=-1))
+        gate, idx = jax.lax.top_k(score, mc.top_k)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9) \
+            * mc.routed_scale
+        local = idx - mc.first_expert
+        mine = (local >= 0) & (local < held)
+        if valid is not None:
+            mine &= valid.reshape(-1, 1)
+    with jax.named_scope("moe.experts"):
+        # one expert axis over all stacked layers: this layer's experts
+        # start at layer * held
+        w = {n: p[n].reshape(-1, *p[n].shape[-2:]) for n in EXPERT_LEAVES}
+        y = _held_experts(w, p.get("layer", 0) * held, h,
+                          jnp.where(mine, local, held), gate, held)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + mlp(p["shared"], cfg, h).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(shape)
+
+
+def _held_experts(w: Dict, first, h, local, gate, held: int):
+    """Sum over each token's held experts of gate * expert(h), f32.
+    w: expert matrices on one expert axis, this layer's from ``first``
+    on; local: (T, k) held expert index of each pair, ``held`` for a
+    pair this chip does not compute. The pairs are laid out by expert
+    in row tiles of ``tm``, each expert's rows starting a tile, in a
+    buffer sized for the most pairs T rows can bring (each token meets
+    an expert at most once), so no pair is ever dropped."""
+    from repro.kernels import ops as kops
+    t, k = local.shape
+    tm = min(128, -(-t // 16) * 16)
+    tiles = min(held * -(-t // tm), -(-t * min(k, held) // tm) + held)
+    m = tiles * tm
+    e = local.reshape(-1)                                  # (T*k,)
+    hit = e[:, None] == jnp.arange(held)[None, :]          # (T*k, held)
+    count = hit.sum(0)
+    rank = jnp.cumsum(hit, 0) - 1
+    e_c = jnp.minimum(e, held - 1)
+    tile_end = jnp.cumsum(-(-count // tm))                 # per expert
+    start = (tile_end - (-(-count // tm))) * tm            # first row
+    row = jnp.where(e < held, start[e_c]
+                    + jnp.take_along_axis(rank, e_c[:, None], 1)[:, 0], m)
+    tok = jnp.arange(t * k) // k
+    # each buffer row's token and gate; rows holding no pair get 0
+    src = jnp.zeros(m, jnp.int32).at[row].set(tok, mode="drop")
+    g = jnp.zeros(m, jnp.float32).at[row].set(gate.reshape(-1),
+                                              mode="drop")
+    live = jnp.zeros(m, bool).at[row].set(True, mode="drop")
+    tile_expert = first + jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(tiles), side="right"),
+        held - 1)
+    live_tiles = tile_end[-1]
+    xs = jnp.take(h, src, axis=0)
+
+    def gmm(a, wts, out_dtype):
+        return kops.expert_gmm(a, wts, tile_expert, live_tiles, tm,
+                               out_dtype)
+    a = jax.nn.silu(gmm(xs, w["w_gate"], jnp.float32)) \
+        * gmm(xs, w["w_up"], jnp.float32)
+    o = gmm(a.astype(h.dtype), w["w_down"], jnp.float32)
+    # rows of tiles past the live ones are unset: select, never scale
+    contrib = jnp.where(live[:, None], o * g[:, None], 0.0)
+    return jnp.zeros((t, h.shape[1]), jnp.float32).at[src].add(contrib)

@@ -446,6 +446,21 @@ class InferenceEngine:
         # <= 1/K gate pass vacuously on mixed-heavy traffic)
         self._decode_only_tokens = 0
         self._occ_slot_iters = 0       # occupied slot-iterations
+        # -- model counters (always on; /metrics) --
+        # token rows through the held expert layers, once per MoE layer
+        # (prefill chunk tokens and decode rows; a chunk's padding and
+        # idle slots are routed nowhere), and the KV pages the paged
+        # decode kernel copies, by attention kind: a windowed layer's
+        # sweep starts at the page of its first key in the window
+        self.moe_rows_total = 0
+        self.attn_pages_swept = {"window": 0, "full": 0}
+        windows = cfg.layer_windows or (0,) * cfg.num_layers
+        self._moe_layers = (cfg.num_layers - cfg.dense_layers
+                            if cfg.moe is not None else 0)
+        self._windows = [w for w in windows if w]
+        self._full_layers = len(windows) - len(self._windows)
+        self._kernel_pages = paged and decode_impl == "pallas" \
+            and self.spec_k == 1
         # -- device-resident decode state (decode_k > 1 scan path) --
         # (last_tok, pos, active, budget) live on device BETWEEN scan
         # dispatches; host mirrors (slot_last_tok / slot_pos / slot_out
@@ -1542,6 +1557,7 @@ class InferenceEngine:
         for s, chunk in chunks.items():
             rid = self.slot_req[s].rid
             self.slot_pos[s] += len(chunk)
+            self.moe_rows_total += self._moe_layers * len(chunk)
             self._prefill_iters[rid] = self._prefill_iters.get(rid, 0) + 1
             if not self.slot_prefill_left[s]:
                 self.slot_last_tok[s] = chunk[-1]
@@ -1785,6 +1801,7 @@ class InferenceEngine:
         """Host mirror of one emitted token; returns True when the slot
         completed (same rule the device scan applies)."""
         req = self.slot_req[s]
+        self._count_decode_row(int(self.slot_pos[s]))
         self.slot_out[s].append(tok)
         self.slot_last_tok[s] = tok
         self.slot_pos[s] += 1
@@ -1792,6 +1809,16 @@ class InferenceEngine:
         return (len(self.slot_out[s]) >= req.max_new_tokens
                 or (self.eos_id is not None and tok == self.eos_id)
                 or self.slot_pos[s] >= self.c_max)
+
+    def _count_decode_row(self, pos: int) -> None:
+        """The model counters for one decode row at position ``pos``."""
+        self.moe_rows_total += self._moe_layers
+        if self._kernel_pages:
+            bs = self.block_size
+            end = -(-(pos + 1) // bs)
+            self.attn_pages_swept["full"] += self._full_layers * end
+            self.attn_pages_swept["window"] += sum(
+                end - max(pos + 1 - w, 0) // bs for w in self._windows)
 
     def _run_decode(self, mask: np.ndarray) -> None:
         tr = self.tracer

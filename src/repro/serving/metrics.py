@@ -127,6 +127,14 @@ def fleet_metrics(runtime) -> List[Metric]:
         "prefix_hit_blocks": Metric(
             "fleetopt_prefix_hit_blocks_total", "counter",
             "Prompt blocks served from the prefix cache"),
+        "moe_rows": Metric(
+            "fleetopt_moe_rows_total", "counter",
+            "Token rows through the held expert layers, once per MoE "
+            "layer"),
+        "pages_swept": Metric(
+            "fleetopt_attn_pages_swept_total", "counter",
+            "KV pages the paged decode kernel copies, by attention kind "
+            "(window or full)"),
     }
     overload = {
         key: Metric(f"fleetopt_{key}_total", "counter", help_)
@@ -164,6 +172,9 @@ def fleet_metrics(runtime) -> List[Metric]:
         per_pool["host_tier"].add(snap["host_tier_blocks"], pool=name)
         per_pool["kv_tokens"].add(eng.kv_tokens_held(), pool=name)
         per_pool["spec_kappa"].add(eng.spec_kappa(), pool=name)
+        per_pool["moe_rows"].add(eng.moe_rows_total, pool=name)
+        for kind, pages in eng.attn_pages_swept.items():
+            per_pool["pages_swept"].add(pages, pool=name, kind=kind)
         for key, metric in overload.items():
             metric.add(snap[key], pool=name)
         if eng.paged and eng.prefix_cache:

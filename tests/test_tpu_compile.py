@@ -1,4 +1,5 @@
-"""The decode kernels compile for a TPU v5e at Minitron-8B widths.
+"""The decode kernels compile for a TPU v5e at Minitron-8B widths, and
+the windowed decode and the experts' grouped matmul at K-EXAONE's.
 
 Nothing runs: the TPU compiler installed with jax compiles for a chip
 that is described, not attached (8 KV heads, 4 or 6 query heads per KV
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.expert_gmm import expert_gmm
 from repro.kernels.gqa_decode import gqa_decode
 from repro.kernels.paged_decode import paged_gqa_decode
 
@@ -74,7 +76,7 @@ def _pool_copies(hlo: str, pool_shape) -> list:
     return found
 
 
-def _compile_paged(sharding, b, h, pages, block_s, nb):
+def _compile_paged(sharding, b, h, pages, block_s, nb, window=None):
     args = (_spec(sharding, (b, h, HD), jnp.bfloat16),
             _spec(sharding, (pages, block_s, HKV, HD), jnp.bfloat16),
             _spec(sharding, (pages, block_s, HKV, HD), jnp.bfloat16),
@@ -82,8 +84,8 @@ def _compile_paged(sharding, b, h, pages, block_s, nb):
             _spec(sharding, (b,), jnp.int32),
             _spec(sharding, (b,), jnp.bool_))
     hlo = jax.jit(
-        lambda q, k, v, bt, sl, act: paged_gqa_decode(q, k, v, bt, sl, act,
-                                                      interpret=False)
+        lambda q, k, v, bt, sl, act: paged_gqa_decode(
+            q, k, v, bt, sl, act, interpret=False, window=window)
     ).lower(*args).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert not _pool_copies(hlo, (pages, block_s, HKV, HD))
@@ -103,3 +105,26 @@ def test_paged_gqa_decode_compiles_at_benchmark_shapes(one_chip, b, nb):
     128. The kernel reads the token-major pool where it lies: no copy of
     it in another layout is left in the module."""
     _compile_paged(one_chip, b, 48, 2048, 16, nb)
+
+
+@pytest.mark.parametrize("b,nb", [(16, 256), (2, 1024)])
+def test_windowed_paged_decode_compiles_at_kexaone_shapes(one_chip, b, nb):
+    """K-EXAONE's windowed layers: 64/8 heads x 128, a 128-key window,
+    the benchmark's pools."""
+    _compile_paged(one_chip, b, 64, 2048, 16, nb, window=128)
+
+
+@pytest.mark.parametrize("rows,tm", [(128, 16), (8192, 128)])
+@pytest.mark.parametrize("kd,n", [(6144, 2048), (2048, 6144)])
+def test_expert_gmm_compiles_at_kexaone_shapes(one_chip, rows, tm, kd, n):
+    """The held experts' grouped matmul: a decode pass (16 rows, tiles
+    of 16) and a 1,024-token prefill chunk (tiles of 128), gate/up
+    (6,144 -> 2,048) and down (2,048 -> 6,144), 4 layers x 8 experts."""
+    args = (_spec(one_chip, (rows, kd), jnp.bfloat16),
+            _spec(one_chip, (32, kd, n), jnp.bfloat16),
+            _spec(one_chip, (rows // tm,), jnp.int32),
+            _spec(one_chip, (), jnp.int32))
+    hlo = jax.jit(lambda x, w, te, live: expert_gmm(
+        x, w, te, live, tm=tm, out_dtype=jnp.float32, interpret=False)
+    ).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
